@@ -230,6 +230,30 @@ def _inverse_permutation(order: jax.Array) -> jax.Array:
         jnp.arange(n, dtype=jnp.int32))
 
 
+def _ragged_exchange(rows, send_sizes, recv_sizes, axis_names, *,
+                     world: int, out_rows: int):
+    """Move variable-size row chunks over the EP axes with
+    ``lax.ragged_all_to_all``: the wire carries only real rows.
+
+    rows is sorted by destination rank (the chunk for rank w starts at
+    ``exclusive_cumsum(send_sizes)[w]``); ``recv_sizes`` is the other half
+    of the size exchange. Returns [out_rows, d], source-major and
+    compacted; rows past ``sum(recv_sizes)`` are unspecified."""
+    send_off = jnp.cumsum(send_sizes) - send_sizes
+    recv_off = jnp.cumsum(recv_sizes) - recv_sizes
+    # output_offsets[w] = where MY chunk lands in w's source-major buffer =
+    # recv_off[me] as computed BY w; one tiny all_to_all hands every source
+    # its own column of the offset matrix
+    out_off = jax.lax.all_to_all(recv_off.reshape(world, 1), axis_names,
+                                 split_axis=0, concat_axis=0,
+                                 tiled=False).reshape(world)
+    out_buf = jnp.zeros((out_rows, rows.shape[1]), rows.dtype)
+    return jax.lax.ragged_all_to_all(
+        rows, out_buf, send_off.astype(jnp.int32),
+        send_sizes.astype(jnp.int32), out_off.astype(jnp.int32),
+        recv_sizes.astype(jnp.int32), axis_name=axis_names)
+
+
 def dispatch_combine_ragged(
     x: jax.Array,                    # [T, d] LOCAL tokens (inside shard_map)
     slots: jax.Array,                # [T, k] destination physical slots
@@ -280,10 +304,8 @@ def dispatch_combine_ragged(
                                        concat_axis=0, tiled=False)
         recv_sizes = recv_cmat.sum(axis=1)                     # [world] by src
         r_buf = n_pairs * world                                # exact bound
-        from repro.launch.mesh import ragged_all_to_all_portable
-        xr = ragged_all_to_all_portable(xs, send_sizes, recv_sizes,
-                                        ep.axis_names, world=world,
-                                        out_rows=r_buf)
+        xr = _ragged_exchange(xs, send_sizes, recv_sizes, ep.axis_names,
+                              world=world, out_rows=r_buf)
         # received rows are source-major; within one source chunk they are
         # local-slot-sorted (the sender sorted by global slot id). Recover
         # each row's local slot from the count matrix, then group-sort.
@@ -300,13 +322,11 @@ def dispatch_combine_ragged(
         group_sizes = recv_cmat.sum(axis=0).astype(jnp.int32)  # [spr]
         yg = grouped_expert_fn(xr[order2], group_sizes)
         # back to source-major, then the mirror exchange returns each pair's
-        # output to its sender in the original dst-sorted order. Each
-        # destination gets back exactly what it sent (<= its n_pairs), so
-        # the fallback's per-destination chunk bound is n_pairs, not r_buf.
-        y_back = ragged_all_to_all_portable(yg[inv2], recv_sizes, send_sizes,
-                                            ep.axis_names, world=world,
-                                            out_rows=n_pairs,
-                                            chunk_rows=n_pairs)
+        # output to its sender in the original dst-sorted order (each
+        # destination gets back exactly what it sent: n_pairs rows)
+        y_back = _ragged_exchange(yg[inv2], recv_sizes, send_sizes,
+                                  ep.axis_names, world=world,
+                                  out_rows=n_pairs)
         y = y_back[inv]
 
     w = weights.reshape(-1).astype(jnp.float32)[:, None]
@@ -317,8 +337,8 @@ def dispatch_combine_ragged(
 def dispatch_bytes_model(ep: EPContext, tokens_per_rank: int, top_k: int,
                          d_model: int, itemsize: int = 2) -> dict:
     """Per-device on-wire bytes of one dispatch+combine round trip, both
-    layouts (analytic; the ragged fallback's HLO shows dense buffers, so
-    accounting must come from here — see ragged_all_to_all_portable).
+    layouts (analytic: the ragged exchange's HLO buffers are sized for the
+    worst case, while the wire carries only the real rows).
 
     dense:  both all_to_alls carry the full capacity-padded buffer
             [world, spr, cap, d] regardless of how many slots are real.

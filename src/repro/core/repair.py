@@ -18,10 +18,10 @@ re-planned to Tier 3.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backup import BackupStore
@@ -229,49 +229,83 @@ def revalidate_plan(
 # Executor
 # ---------------------------------------------------------------------------
 
-def tier2_gather_indices(plan: RepairPlan) -> np.ndarray:
-    """src index per slot for the single batched Tier-2 gather
-    (identity everywhere except relocated destinations)."""
-    idx = np.arange(plan.num_slots, dtype=np.int32)
-    for dst, src in plan.tier2:
-        idx[dst] = src
-    return idx
+@dataclass
+class SlotMoves:
+    """The weight movement of one or more composed repair plans, staged as
+    what each MOVED slot will hold: a live source slot (Tier 2, an index)
+    or a host copy from the backup service (Tier 3, arrays [L, ...]).
+    Unlisted slots keep their contents. Nothing touches the device until
+    :func:`apply_slot_moves`, so staging never holds a second copy of the
+    expert weights and discarding it needs no undo."""
+
+    src: dict[int, int] = field(default_factory=dict)     # dst -> live slot
+    host: dict[int, object] = field(default_factory=dict)  # dst -> pytree
+
+    def __bool__(self) -> bool:
+        return bool(self.src or self.host)
+
+    def then(self, plan: RepairPlan,
+             backup: Optional[BackupStore] = None) -> "SlotMoves":
+        """Compose ``plan`` on top of these moves. The plan was made against
+        the STAGED placement, so a Tier-2 source reads what earlier plans
+        put in that slot; all Tier-2 reads see the pre-plan state (one
+        simultaneous gather), then Tier-3 reloads land."""
+        src, host = dict(self.src), dict(self.host)
+        for dst, s in plan.tier2:
+            src.pop(dst, None)
+            host.pop(dst, None)
+            if s in self.host:
+                host[dst] = self.host[s]
+            elif self.src.get(s, s) != dst:
+                src[dst] = self.src.get(s, s)
+        if plan.tier3:
+            assert backup is not None, "Tier-3 repairs need a backup store"
+        for dst, e in plan.tier3:
+            src.pop(dst, None)
+            host[dst] = backup.fetch(e)
+        return SlotMoves(src, host)
 
 
-def apply_tier2(slot_weights, plan: RepairPlan):
-    """One batched gather over the slot axis (axis=1 of every [L, S, ...]
-    leaf). Under EP sharding XLA lowers this to the batched EP-axis transfer
-    schedule; in single-device simulation it is a local gather."""
-    if not plan.tier2:
+@partial(jax.jit, donate_argnums=(0,))
+def _write_slots(slot_weights, dst2, src2, dst3, rows3):
+    def write(a, rows):
+        if dst2.shape[0]:
+            a = a.at[:, dst2].set(a[:, src2])
+        if rows is not None:
+            a = a.at[:, dst3].set(rows.astype(a.dtype))
+        return a
+    if rows3 is None:
+        return jax.tree_util.tree_map(lambda a: write(a, None), slot_weights)
+    return jax.tree_util.tree_map(write, slot_weights, rows3)
+
+
+def apply_slot_moves(slot_weights, moves: SlotMoves):
+    """Write the moved slots into the slot-stacked leaves (slot axis 1 of
+    every [L, S, ...] leaf) as ONE jitted update that DONATES the input
+    leaves: Tier-2 moves are one batched gather over the slot axis (under
+    EP sharding, the batched EP-axis transfer schedule), Tier-3 rows are
+    one scatter of the host copies. The update is in place, so a repair
+    never holds two copies of the expert weights; the input leaves are
+    consumed."""
+    if not moves:
         return slot_weights
-    idx = jnp.asarray(tier2_gather_indices(plan))
-    return jax.tree_util.tree_map(lambda a: jnp.take(a, idx, axis=1),
-                                  slot_weights)
-
-
-def apply_tier3(slot_weights, plan: RepairPlan, backup: BackupStore):
-    """Batched DRAM-backed reload: fetch host copies, one scatter per leaf."""
-    if not plan.tier3:
-        return slot_weights
-    dst = jnp.asarray(np.array([d for d, _ in plan.tier3], np.int32))
-    fetched = [backup.fetch(e) for _, e in plan.tier3]   # list of pytrees
-    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs, axis=1), *fetched)
-    # stacked leaves: [L, n_t3, ...] matching slot axis semantics
-    def scatter(a, upd):
-        return a.at[:, dst].set(jnp.asarray(upd, a.dtype))
-    return jax.tree_util.tree_map(scatter, slot_weights, stacked)
+    dst2 = sorted(moves.src)
+    dst3 = sorted(moves.host)
+    rows3 = (jax.tree_util.tree_map(lambda *xs: np.stack(xs, axis=1),
+                                    *[moves.host[d] for d in dst3])
+             if dst3 else None)
+    return _write_slots(slot_weights, np.asarray(dst2, np.int32),
+                        np.asarray([moves.src[d] for d in dst2], np.int32),
+                        np.asarray(dst3, np.int32), rows3)
 
 
 def apply_repair(slot_weights, plan: RepairPlan,
                  backup: Optional[BackupStore] = None):
-    """Full repair: Tier-2 batched relocation, then Tier-3 reloads.
+    """Full repair of one plan: Tier-2 batched relocation, then Tier-3
+    reloads (consumes ``slot_weights``, see :func:`apply_slot_moves`).
     Tier 1 requires no data movement (metadata was already updated by the
     placement publish)."""
-    out = apply_tier2(slot_weights, plan)
-    if plan.tier3:
-        assert backup is not None, "Tier-3 repairs need a backup store"
-        out = apply_tier3(out, plan, backup)
-    return out
+    return apply_slot_moves(slot_weights, SlotMoves().then(plan, backup))
 
 
 # ---------------------------------------------------------------------------

@@ -8,10 +8,12 @@ slot-stacked params and the device-published :class:`MembershipState` —
 whether triggered by a fault, a deferred join, a straggler re-place, or a
 *planned* drain/scale operation — flows through one
 :class:`MembershipTransaction`. The transaction stages all mutations on a
-cloned :class:`~repro.core.membership.PeerTable` plus a staged copy of the
-MoE slot leaves, and only :meth:`MembershipTransaction.commit` swaps them
-into the live runtime, so the core invariants are enforced structurally
-instead of re-asserted in every handler:
+cloned :class:`~repro.core.membership.PeerTable` plus the staged moves of
+the MoE slots (:class:`~repro.core.repair.SlotMoves`: source indices and
+host copies of the moved slots only), and only
+:meth:`MembershipTransaction.commit` applies them to the live runtime, so
+the core invariants are enforced structurally instead of re-asserted in
+every handler:
 
   * **epoch** — each commit bumps the host's monotonically increasing
     epoch and publishes it as ``MembershipState.version`` (subsuming the
@@ -45,8 +47,8 @@ from typing import TYPE_CHECKING, Optional, Protocol, runtime_checkable
 import numpy as np
 
 from repro.core.placement import PlacementResult, eplb_place
-from repro.core.repair import RepairPlan, apply_repair, plan_repair, \
-    revalidate_plan
+from repro.core.repair import RepairPlan, SlotMoves, apply_slot_moves, \
+    plan_repair, revalidate_plan
 from repro.core.validity import check as validity_check
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (runtime hosts us)
@@ -159,18 +161,19 @@ class MembershipTransaction:
     ``params``, ``table``, ``membership``, ``backup``, ``detector``,
     ``expert_load``, ``epoch``, ``record()``) — in practice an
     :class:`~repro.runtime.elastic.ElasticEPRuntime`. All mutations land on
-    a cloned table and a staged leaf dict; nothing touches the host until
+    a cloned table and staged slot moves; nothing touches the host until
     :meth:`commit`, which (in order) re-runs the validity check against the
     staged state, bumps the host epoch, stamps it into
-    ``MembershipState.version``, publishes the device arrays and swaps
-    table/params/membership in one step. Any failure before the swap leaves
-    the host byte-identical.
+    ``MembershipState.version``, writes the moved slots into the live
+    expert weights (one donating update), publishes the device arrays and
+    swaps table/params/membership in one step. Any failure before the
+    write leaves the host byte-identical.
 
     Cascade composition: :meth:`plan` may be called repeatedly (each call
     replans from the *staged* placement), :meth:`revalidate` re-checks an
     in-flight plan against the staged active bitmap after further
     deactivations, and :meth:`apply` folds a plan's weight movement into
-    the staged leaves — exactly the loop ``handle_failure`` drives when
+    the staged slot moves — exactly the loop ``handle_failure`` drives when
     failures land mid-recovery.
     """
 
@@ -185,7 +188,7 @@ class MembershipTransaction:
         self.repair_plan: Optional[RepairPlan] = None
         self.plans: list[RepairPlan] = []        # every applied plan, in order
         self.rank_capacity: Optional[np.ndarray] = None
-        self._staged_leaves: Optional[dict] = None
+        self._moves = SlotMoves()               # staged weight movement
         self.epoch: Optional[int] = None         # set on commit
         # planned drains: the KV pages shipped off the departing ranks
         # inside this transaction's window (set by the runtime between the
@@ -233,14 +236,8 @@ class MembershipTransaction:
         return self.table.active_mask
 
     # -- plan ----------------------------------------------------------------
-    def slot_leaves(self) -> dict:
-        if self._staged_leaves is None:
-            self._staged_leaves = moe_slot_leaves(self.host.cfg,
-                                                  self.host.params)
-        return self._staged_leaves
-
     def bytes_per_slot(self) -> int:
-        return slot_bytes(self.slot_leaves())
+        return slot_bytes(moe_slot_leaves(self.host.cfg, self.host.params))
 
     def plan(self, *, source_active: Optional[np.ndarray] = None
              ) -> Optional[RepairPlan]:
@@ -288,8 +285,9 @@ class MembershipTransaction:
         return self.repair_plan
 
     def apply(self) -> None:
-        """Fold the current plan's weight movement into the staged leaves
-        and stage the new placement. Aborts if the plan lost experts."""
+        """Fold the current plan's weight movement into the staged slot
+        moves and stage the new placement. Aborts if the plan lost
+        experts."""
         self._live()
         plan = self.repair_plan
         if plan is None:                    # non-MoE: nothing to move
@@ -298,8 +296,7 @@ class MembershipTransaction:
             lost = sorted(plan.unrecoverable)
             self._fail(f"experts {lost} lost every live replica and backup "
                        f"copy", experts=lost)
-        self._staged_leaves = apply_repair(self.slot_leaves(), plan,
-                                           self.host.backup)
+        self._moves = self._moves.then(plan, self.host.backup)
         self.table.set_placement(self.placement.slot_to_expert)
         self.plans.append(plan)
         self.repair_plan = None
@@ -326,9 +323,6 @@ class MembershipTransaction:
         host = self.host
         if self.repair_plan is not None:    # planned but never applied
             self.apply()
-        new_params = (host.params if self._staged_leaves is None
-                      else set_moe_slot_leaves(host.params,
-                                               self._staged_leaves))
         epoch = host.epoch + 1
         self.table.version = epoch          # device version IS the epoch
         staged = self.table.to_device()
@@ -338,6 +332,12 @@ class MembershipTransaction:
             if not rep.valid:
                 self._fail(f"validity check failed: {rep.violations[:3]}",
                            violations=rep.violations)
+        # the moved slots land in place: the update donates the live slot
+        # leaves, so the old params tree is consumed here
+        new_params = host.params
+        if self._moves:
+            new_params = set_moe_slot_leaves(host.params, apply_slot_moves(
+                moe_slot_leaves(host.cfg, host.params), self._moves))
         # the swap: atomic from the serving loop's point of view (between
         # forward passes; nothing below can raise)
         host.table = self.table

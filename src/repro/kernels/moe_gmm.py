@@ -147,18 +147,18 @@ def _gmm_metadata(group_sizes, bt: int, nblocks: int):
 
 def _gmm_kernel(tg_ref, tb_ref, gs_ref, ge_ref, x_ref, w_ref, o_ref, acc_ref,
                 *, bt: int):
-    i = pl.program_id(0)
-    k = pl.program_id(1)
+    i = pl.program_id(1)
+    k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]                                  # [bt, bk]
-    w = w_ref[0]                                    # [bk, f]
+    w = w_ref[0]                                    # [bk, bf]
     acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
-    @pl.when(k == pl.num_programs(1) - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _():
         # blend-store only the rows belonging to this tile's group: a
         # boundary block is completed by its other group's visit(s), and
@@ -172,8 +172,14 @@ def _gmm_kernel(tg_ref, tb_ref, gs_ref, ge_ref, x_ref, w_ref, o_ref, acc_ref,
                                ).astype(o_ref.dtype)
 
 
+def _sublane_rows(dtype) -> int:
+    """Least legal row-block multiple: 8 rows of 32 bits per sublane tile,
+    so 16 for bf16 and 32 for 8-bit types (packed rows)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def gmm(x, w, group_sizes, *, block_t: int = 128, block_k: int = 512,
-        interpret: bool = False):
+        block_f: int = 1024, interpret: bool = False):
     """Grouped matmul over group-sorted rows: the first ``group_sizes[0]``
     rows of x [T, d] belong to group 0, and so on; w [G, d, f].
 
@@ -181,12 +187,20 @@ def gmm(x, w, group_sizes, *, block_t: int = 128, block_k: int = 512,
     of ``block_t`` — boundary row blocks are revisited once per overlapping
     group with a row mask, so ragged dispatch needs NO per-group padding.
     Rows beyond ``sum(group_sizes)`` (receive-buffer slack) produce
-    unspecified output; callers must never read them. Returns [T, f]."""
+    unspecified output; callers must never read them. Returns [T, f].
+
+    Grid (f-blocks, logical tiles, k-blocks): the output width is tiled so
+    the weight block (1, bk, bf) and the fp32 accumulator (bt, bf) stay in
+    the default scoped VMEM at any expert width. The f axis is OUTERMOST so
+    that, for each output column block, the revisits of a boundary row
+    block by successive tiles stay consecutive (the blend-store reads the
+    rows the previous group's visit left in the resident output block)."""
     T, d = x.shape
     G, _, f = w.shape
     if T == 0:
         return jnp.zeros((0, f), x.dtype)
-    bt = min(block_t, max(8, T))
+    sub = _sublane_rows(x.dtype)
+    bt = min(block_t, -(-T // sub) * sub)
     pad_t = (-T) % bt
     if pad_t:
         x = jnp.pad(x, ((0, pad_t), (0, 0)))
@@ -199,25 +213,31 @@ def gmm(x, w, group_sizes, *, block_t: int = 128, block_k: int = 512,
         w = jnp.pad(w, ((0, 0), (0, pad_k), (0, 0)))
     dp = d + pad_k
 
+    bf = min(block_f, f)
+    pad_f = (-f) % bf
+    if pad_f:
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, pad_f)))
+    fp = f + pad_f
+
     tile_group, tile_block, starts, ends = _gmm_metadata(group_sizes, bt,
                                                          nblocks)
     kernel = functools.partial(_gmm_kernel, bt=bt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(nblocks + G - 1, dp // bk),
+        grid=(fp // bf, nblocks + G - 1, dp // bk),
         in_specs=[
-            pl.BlockSpec((bt, bk), lambda i, k, tg, tb, gs, ge: (tb[i], k)),
-            pl.BlockSpec((1, bk, f),
-                         lambda i, k, tg, tb, gs, ge: (tg[i], k, 0)),
+            pl.BlockSpec((bt, bk), lambda j, i, k, tg, tb, gs, ge: (tb[i], k)),
+            pl.BlockSpec((1, bk, bf),
+                         lambda j, i, k, tg, tb, gs, ge: (tg[i], k, j)),
         ],
-        out_specs=pl.BlockSpec((bt, f),
-                               lambda i, k, tg, tb, gs, ge: (tb[i], 0)),
-        scratch_shapes=[pltpu.VMEM((bt, f), jnp.float32)],
+        out_specs=pl.BlockSpec((bt, bf),
+                               lambda j, i, k, tg, tb, gs, ge: (tb[i], j)),
+        scratch_shapes=[pltpu.VMEM((bt, bf), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T + pad_t, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((T + pad_t, fp), x.dtype),
         interpret=interpret,
     )(tile_group, tile_block, starts, ends, x, w)
-    return out[:T]
+    return out[:T, :f]

@@ -1,6 +1,6 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU,
+``interpret`` defaults to True on CPU and False on TPU,
 where the kernels lower natively. The XLA model path (models/*) remains the
 portable implementation; these kernels are the TPU hot-path variants and are
 cross-validated against ``ref.py`` in tests/test_kernels.py.

@@ -92,10 +92,10 @@ def collective_bytes(hlo_text: str) -> tuple[int, Counter]:
 
 def dispatch_model(cfg, shape, mesh, dpl, dtype_bytes: int = 2):
     """Analytic dense-vs-ragged dispatch bytes for one MoE cell (per device,
-    one dispatch+combine round trip per MoE layer). HLO byte counting cannot
-    see the ragged saving on jax versions where ragged_all_to_all falls back
-    to the dense exchange, so this model is the trajectory source of truth
-    (see core.elastic_moe.dispatch_bytes_model)."""
+    one dispatch+combine round trip per MoE layer). HLO byte counting sees
+    the ragged exchange's worst-case receive buffer, not the real rows on the
+    wire, so this model is the trajectory source of truth (see
+    core.elastic_moe.dispatch_bytes_model)."""
     from repro.core.elastic_moe import dispatch_bytes_model
     ep = dpl.moe.ep
     if not cfg.is_moe or not ep.axis_names:

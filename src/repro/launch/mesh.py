@@ -1,119 +1,26 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS so importing this module never touches jax device state
-(the dry-run sets XLA_FLAGS before any jax import).
+(the dry-run sets XLA_FLAGS before any jax import). Every axis is Auto: the
+model's shard_map islands and sharding rules are written for GSPMD
+propagation, not explicit per-axis types.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
-
-
-def make_mesh_portable(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; older ones
-    default every axis to Auto anyway, which is what we want."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def shard_map_portable(f, *, mesh, in_specs, out_specs, check=False):
-    """jax.shard_map across jax versions: newer releases expose it as
-    ``jax.shard_map(..., check_vma=...)``; 0.4.x has it under
-    ``jax.experimental.shard_map`` with the flag named ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check)
-
-
-def ragged_all_to_all_portable(rows, send_sizes, recv_sizes, axis_names, *,
-                               world: int, out_rows: int,
-                               chunk_rows: int = 0):
-    """Exchange variable-size row chunks over the EP mesh axes (the dropless
-    dispatch's token move), portable across jax versions.
-
-    rows:       [R_in, d], sorted by destination rank — chunk for rank w is
-                ``rows[send_off[w] : send_off[w] + send_sizes[w]]``.
-    send_sizes: int32[world], rows this rank sends to each destination.
-    recv_sizes: int32[world], rows this rank receives from each source
-                (the other half of the size exchange).
-    out_rows:   static receive-buffer bound (>= sum(recv_sizes) whenever the
-                caller used the exact worst case).
-    chunk_rows: static bound on any SINGLE destination's chunk
-                (max over w of send_sizes[w]); 0 means rows.shape[0] — right
-                for the dispatch direction, where one destination can
-                receive everything. The combine direction returns each
-                source exactly what it sent, so its per-destination bound is
-                that rank's pair count, much smaller than the full receive
-                buffer — pass it to keep the fallback buffer tight.
-
-    Returns [out_rows, d]: received rows, source-major and compacted — the
-    chunk from source s starts at ``exclusive_cumsum(recv_sizes)[s]``. Rows
-    past ``sum(recv_sizes)`` are unspecified.
-
-    On jax versions with ``lax.ragged_all_to_all`` the wire carries only real
-    rows. Older releases (0.4.x) fall back to a tight dense exchange: one
-    ``all_to_all`` of [world, chunk_rows, d] — the exact per-destination
-    worst case, so semantics are identical and the buffer is as small as a
-    dense layout allows — plus local compaction. Byte accounting for the ragged
-    path must therefore come from the analytic model
-    (``core.elastic_moe.dispatch_bytes_model``), not fallback HLO.
-    """
-    r_in, _ = rows.shape
-    send_off = jnp.cumsum(send_sizes) - send_sizes
-    recv_off = jnp.cumsum(recv_sizes) - recv_sizes
-
-    ragged = getattr(jax.lax, "ragged_all_to_all", None)
-    if ragged is not None:
-        # output_offsets[w] = where MY chunk lands in w's source-major
-        # buffer = recv_off[me] as computed BY w; one tiny all_to_all hands
-        # every source its own column of the offset matrix.
-        out_off = jax.lax.all_to_all(
-            recv_off.reshape(world, 1), axis_names, split_axis=0,
-            concat_axis=0, tiled=False).reshape(world)
-        out_buf = jnp.zeros((out_rows, rows.shape[1]), rows.dtype)
-        return ragged(rows, out_buf, send_off.astype(jnp.int32),
-                      send_sizes.astype(jnp.int32),
-                      out_off.astype(jnp.int32),
-                      recv_sizes.astype(jnp.int32), axis_name=axis_names)
-
-    # ---- tight dense fallback (jax 0.4.x) --------------------------------
-    cr = chunk_rows or r_in
-    idx = jnp.arange(r_in)
-    dst = jnp.clip(jnp.searchsorted(send_off, idx, side="right") - 1,
-                   0, world - 1)
-    pos = idx - send_off[dst]
-    flat = dst * cr + pos
-    valid = (idx < send_sizes.sum()) & (pos < cr)
-    flat = jnp.where(valid, flat, world * cr)            # OOB -> dropped
-    buf = jnp.zeros((world * cr, rows.shape[1]), rows.dtype)
-    buf = buf.at[flat].set(rows, mode="drop").reshape(world, cr, -1)
-    got = jax.lax.all_to_all(buf, axis_names, split_axis=0, concat_axis=0,
-                             tiled=False)
-    # compact [world, chunk, d] -> [out_rows, d] source-major
-    j = jnp.arange(cr)[None, :]
-    tgt = recv_off[:, None] + j
-    tgt = jnp.where(j < recv_sizes[:, None], tgt, out_rows)
-    out = jnp.zeros((out_rows, rows.shape[1]), rows.dtype)
-    return out.at[tgt.reshape(-1)].set(got.reshape(world * cr, -1),
-                                       mode="drop")
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_portable(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names (smoke tests)."""
-    return make_mesh_portable((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def required_devices(multi_pod: bool = False) -> int:

@@ -32,6 +32,39 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def build_frontend(cfg, *, world: int, slots_per_rank: int = 1,
+                   topology=None, seed: int = 0, dtype=jnp.float32,
+                   dispatch=None, detect_timeout=None, max_batch: int = 8,
+                   max_len: int = 32, fixed_membership: bool = False,
+                   kv_pool=None, queue_policy: str = "fifo",
+                   max_queue_depth=None, tenant_quotas=None):
+    """One serving instance: membership table (``world`` ranks x
+    ``slots_per_rank`` slots), params drawn from ``seed`` in ``dtype``,
+    the elastic runtime, the engine (KV caches in ``dtype``) and its
+    frontend. ``topology`` defaults to the config's host/switch layout.
+    Returns (runtime, engine, frontend)."""
+    from repro.core import make_initial_membership
+    from repro.models import init_params
+    from repro.runtime.elastic import ElasticEPRuntime
+    from repro.serving.api import ServingFrontend
+    from repro.serving.engine import ServingEngine
+
+    E = cfg.moe.num_experts if cfg.is_moe else 1
+    table = make_initial_membership(world, E, slots_per_rank,
+                                    topology=topology)
+    params = init_params(cfg, jax.random.key(seed), dtype,
+                         table.slot_to_expert, table.num_slots)
+    rt = ElasticEPRuntime(cfg, params, table, dispatch=dispatch)
+    if detect_timeout is not None:
+        rt.detector.timeout_s = detect_timeout
+    eng = ServingEngine(rt, max_batch=max_batch, max_len=max_len,
+                        dtype=dtype, fixed_membership=fixed_membership,
+                        kv_pool=kv_pool, queue_policy=queue_policy)
+    fe = ServingFrontend(eng, max_queue_depth=max_queue_depth,
+                         tenant_quotas=tenant_quotas or {})
+    return rt, eng, fe
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -121,12 +154,10 @@ def main(argv=None):
     import json
 
     from repro.configs import get_config
-    from repro.core import make_initial_membership
-    from repro.models import init_params
-    from repro.runtime.elastic import ElasticEPRuntime
-    from repro.serving.api import ServingFrontend
-    from repro.serving.engine import ServingEngine
+    from repro.core.topology import FaultDomainTree
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
@@ -138,31 +169,24 @@ def main(argv=None):
         if args.prefix_cache is not None:
             repl["prefix_cache"] = args.prefix_cache == "on"
         cfg = dataclasses.replace(cfg, **repl)
-    E = cfg.moe.num_experts if cfg.is_moe else 1
-    from repro.core.topology import FaultDomainTree
     rph = args.ranks_per_host or cfg.ranks_per_host
     if args.hosts is not None:
         rph = -(-args.world // args.hosts)     # pack ranks onto N hosts
     topology = FaultDomainTree(
         args.world, ranks_per_host=rph,
         hosts_per_switch=args.hosts_per_switch or cfg.hosts_per_switch)
-    table = make_initial_membership(args.world, E, args.slots_per_rank,
-                                    topology=topology)
-    params = init_params(cfg, jax.random.key(args.seed), jnp.float32,
-                         table.slot_to_expert, table.num_slots)
-    rt = ElasticEPRuntime(cfg, params, table, dispatch=args.dispatch)
-    if args.detect_timeout is not None:
-        rt.detector.timeout_s = args.detect_timeout
-    eng = ServingEngine(rt, max_batch=args.max_batch,
-                        max_len=args.prompt_len + args.max_new + 8,
-                        fixed_membership=args.fixed_membership,
-                        kv_pool=args.kv_pool, queue_policy=args.sched)
     quotas = {}
     for spec in (args.tenant_quota or []):
         name, _, n = spec.partition("=")
         quotas[name] = int(n)
-    fe = ServingFrontend(eng, max_queue_depth=args.max_queue_depth,
-                         tenant_quotas=quotas)
+    rt, eng, fe = build_frontend(
+        cfg, world=args.world, slots_per_rank=args.slots_per_rank,
+        topology=topology, seed=args.seed, dispatch=args.dispatch,
+        detect_timeout=args.detect_timeout, max_batch=args.max_batch,
+        max_len=args.prompt_len + args.max_new + 8,
+        fixed_membership=args.fixed_membership, kv_pool=args.kv_pool,
+        queue_policy=args.sched, max_queue_depth=args.max_queue_depth,
+        tenant_quotas=quotas)
 
     if args.http is not None:
         # off-box mode: everything below (inline submits, scheduled admin

@@ -173,26 +173,16 @@ def main(argv=None):
         card["sched"] = args.sched
         card["seed"] = args.seed
     else:
-        import jax
-        import jax.numpy as jnp
+        from repro.launch.compile_cache import enable_compile_cache
+        from repro.launch.serve import build_frontend
 
-        from repro.core import make_initial_membership
-        from repro.models import init_params
-        from repro.runtime.elastic import ElasticEPRuntime
-        from repro.serving.api import ServingFrontend
-        from repro.serving.engine import ServingEngine
-
-        E = cfg.moe.num_experts if cfg.is_moe else 1
-        table = make_initial_membership(args.world, E, args.slots_per_rank)
-        params = init_params(cfg, jax.random.key(args.seed), jnp.float32,
-                             table.slot_to_expert, table.num_slots)
-        rt = ElasticEPRuntime(cfg, params, table)
-        eng = ServingEngine(rt, max_batch=args.max_batch,
-                            max_len=args.max_len,
-                            fixed_membership=args.fixed_membership,
-                            kv_pool=args.kv_pool, queue_policy=args.sched)
-        fe = ServingFrontend(eng, max_queue_depth=args.max_queue_depth,
-                             tenant_quotas=spec.quotas())
+        enable_compile_cache()
+        rt, eng, fe = build_frontend(
+            cfg, world=args.world, slots_per_rank=args.slots_per_rank,
+            seed=args.seed, max_batch=args.max_batch, max_len=args.max_len,
+            fixed_membership=args.fixed_membership, kv_pool=args.kv_pool,
+            queue_policy=args.sched, max_queue_depth=args.max_queue_depth,
+            tenant_quotas=spec.quotas())
 
         # mid-storm events are scheduled BEFORE anything serves: the
         # injector fires when the sim clock crosses, whichever driver is

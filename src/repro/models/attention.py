@@ -226,9 +226,7 @@ def gqa_decode_seqsharded(cfg: ArchConfig, p, x, lengths, cache,
     Runs inside shard_map; cache leaves here are the LOCAL shard [B, W/n, ...].
     New k/v land on the shard owning slot ``pos % W``."""
     idx = jax.lax.axis_index(axis)
-    # jax.lax.axis_size is missing on jax 0.4.x; psum(1) is the portable form
-    n = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis))
+    n = jax.lax.axis_size(axis)
     positions = lengths[:, None]
     q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
     k = jnp.einsum("bsd,dhe->bshe", x, p["wk"])

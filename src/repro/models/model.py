@@ -9,6 +9,7 @@ the compiled function (the paper's graph-stable/content-mutable contract).
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -96,11 +97,22 @@ def _init_period(cfg, group: ScanGroup, key, dtype, slot_to_expert,
 def init_params(cfg: ArchConfig, key, dtype=jnp.float32,
                 slot_to_expert: Optional[np.ndarray] = None,
                 num_slots: Optional[int] = None, serving: bool = False):
-    """Real initialization (smoke tests / examples). The dry-run uses
-    ``param_shapes`` (no allocation)."""
+    """Real initialization, as ONE jitted program: every weight is drawn
+    straight into its final (period-stacked, slot-stacked) buffer, so no
+    eager fp32 draw, per-slot gather or stack copy holds a second full set.
+    The dry-run uses ``param_shapes`` (no allocation)."""
     if cfg.is_moe and slot_to_expert is None:
         num_slots = num_slots or cfg.moe.num_experts
         slot_to_expert = np.arange(num_slots) % cfg.moe.num_experts
+    s2e = (None if slot_to_expert is None
+           else tuple(int(e) for e in np.asarray(slot_to_expert)))
+    return _init_params_jit(cfg, key, dtype=jnp.dtype(dtype), s2e=s2e,
+                            num_slots=num_slots, serving=serving)
+
+
+def _init_params_body(cfg: ArchConfig, key, *, dtype, s2e, num_slots,
+                      serving):
+    slot_to_expert = None if s2e is None else np.asarray(s2e)
     params = {
         "embed": embed_init(key, cfg.vocab_size, cfg.d_model, dtype),
         "final_norm": norm_init(cfg.norm, cfg.d_model, dtype),
@@ -110,24 +122,30 @@ def init_params(cfg: ArchConfig, key, dtype=jnp.float32,
         params["lm_head"] = embed_init(jax.random.fold_in(key, 1),
                                        cfg.vocab_size, cfg.d_model, dtype).T
     for g in build_groups(cfg):
-        gk = jax.random.fold_in(key, hash(g.name) % (2**31))
-        periods = [_init_period(cfg, g, jax.random.fold_in(gk, p), dtype,
-                                slot_to_expert, num_slots, serving)
-                   for p in range(g.n_periods)]
-        params["groups"][g.name] = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *periods)
+        # crc32, not hash(): str hashes are salted per process, and the
+        # same seed must give the same weights in every process
+        gk = jax.random.fold_in(key, zlib.crc32(g.name.encode()) % (2**31))
+        keys = jax.vmap(lambda p: jax.random.fold_in(gk, p))(
+            jnp.arange(g.n_periods))
+        params["groups"][g.name] = jax.vmap(
+            lambda k: _init_period(cfg, g, k, dtype, slot_to_expert,
+                                   num_slots, serving))(keys)
     if cfg.encoder is not None:
         ek = jax.random.fold_in(key, 2)
         enc_spec = LayerSpec("attn", "dense")
-        periods = [
-            {"layer0": _init_layer(cfg, enc_spec, jax.random.fold_in(ek, p),
-                                   dtype, slot_to_expert, num_slots)}
-            for p in range(cfg.encoder.num_layers)]
+        keys = jax.vmap(lambda p: jax.random.fold_in(ek, p))(
+            jnp.arange(cfg.encoder.num_layers))
         params["encoder"] = {
-            "layers": jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *periods),
+            "layers": jax.vmap(lambda k: {"layer0": _init_layer(
+                cfg, enc_spec, k, dtype, slot_to_expert, num_slots)})(keys),
             "final_norm": norm_init(cfg.norm, cfg.d_model, dtype),
         }
     return params
+
+
+_init_params_jit = jax.jit(
+    _init_params_body, static_argnums=(0,),
+    static_argnames=("dtype", "s2e", "num_slots", "serving"))
 
 
 def param_shapes(cfg: ArchConfig, dtype=jnp.bfloat16,
@@ -363,14 +381,13 @@ def _seqsharded_decode(cfg, p, h, lengths, lc, dpl: Deployment):
     from jax.sharding import PartitionSpec as P
     ax = dpl.seq_shard_axis
     cache_specs = {"k": P(None, ax), "v": P(None, ax), "pos": P(None, ax)}
-    from repro.launch.mesh import shard_map_portable
-    fn = shard_map_portable(
+    fn = jax.shard_map(
         partial(attn.gqa_decode_seqsharded, cfg, axis=ax),
         mesh=dpl.mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(), p), P(), P(),
                   cache_specs),
         out_specs=(P(), cache_specs),
-        check=False,
+        check_vma=False,
     )
     return fn(p, h, lengths, {k: lc[k] for k in ("k", "v", "pos")})
 

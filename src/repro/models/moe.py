@@ -97,21 +97,24 @@ def moe_layer_init(key, cfg: ArchConfig, num_slots: int,
     e_dtype = jnp.dtype(expert_dtype) if expert_dtype else dtype
     ks = jax.random.split(key, 5)
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(de)
+    idx = jnp.asarray(np.clip(np.asarray(slot_to_expert), 0, E - 1))
 
     def logical(k, shape, scale):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
-    w_in = logical(ks[0], (E, d, de), s_in).astype(e_dtype)
-    w_out = logical(ks[1], (E, de, d), s_out).astype(e_dtype)
-    idx = np.clip(np.asarray(slot_to_expert), 0, E - 1)
+    def per_slot(k, shape, scale):
+        # every slot draws from its logical expert's key: replicas hold
+        # identical weights, written straight into the [S, ...] layout
+        return jax.vmap(lambda e: logical(jax.random.fold_in(k, e), shape,
+                                          scale))(idx).astype(e_dtype)
+
     p = {
         "router": logical(ks[2], (d, E), s_in),
-        "w_in": w_in[idx],       # [S, d, de] replicas share logical weights
-        "w_out": w_out[idx],
+        "w_in": per_slot(ks[0], (d, de), s_in),
+        "w_out": per_slot(ks[1], (de, d), s_out),
     }
     if is_gated(cfg.activation):
-        w_gate = logical(ks[3], (E, d, de), s_in).astype(e_dtype)
-        p["w_gate"] = w_gate[idx]
+        p["w_gate"] = per_slot(ks[3], (d, de), s_in)
     if m.num_shared_experts:
         dse = m.d_shared_expert * m.num_shared_experts
         p["shared"] = {
@@ -270,6 +273,26 @@ def _moe_island(x, router, w_in, w_gate, w_out, shared, membership,
     return y, load, aux["dropped_fraction"]
 
 
+def moe_param_specs(dep: MoEDeployment, p) -> dict:
+    """PartitionSpecs of one MoE layer's params ``p`` on ``dep.mesh``: slot
+    weights sharded over the EP axes (slot dim) and the expert-TP axes
+    (expert hidden dim), router replicated. The shard_map island's
+    in_specs, and the shardings to draw the weights into."""
+    ep_axes = tuple(dep.ep.axis_names)
+    tp = tuple(dep.tp_axes)
+    tp_spec = tp[0] if len(tp) == 1 else (tp if tp else None)
+    ep_spec = ep_axes[0] if len(ep_axes) == 1 else ep_axes
+    specs = dict(router=P(None, None),
+                 w_in=P(ep_spec, None, tp_spec),
+                 w_out=P(ep_spec, tp_spec, None))
+    if "w_gate" in p:
+        specs["w_gate"] = P(ep_spec, None, tp_spec)
+    if "shared" in p:
+        specs["shared"] = {k: (P(tp_spec, None) if k == "w_out"
+                               else P(None, tp_spec)) for k in p["shared"]}
+    return specs
+
+
 def moe_apply(cfg: ArchConfig, p, x, membership: MembershipState,
               dep: MoEDeployment, fixed_s2e: Optional[np.ndarray] = None):
     """x: [T, d] tokens (global view). Returns (y [T, d], aux dict).
@@ -298,29 +321,18 @@ def moe_apply(cfg: ArchConfig, p, x, membership: MembershipState,
 
     body = partial(_moe_island, cfg=cfg, dep=dep, fixed_s2e=fixed_s2e,
                    x_axes=x_axes)
-    tp = tuple(dep.tp_axes)
-    tp_spec = tp[0] if len(tp) == 1 else (tp if tp else None)
-    ep_spec = ep_axes[0] if len(ep_axes) == 1 else ep_axes
     x_spec = x_axes[0] if len(x_axes) == 1 else x_axes
-
-    specs = dict(
-        x=P(x_spec, None),
-        router=P(None, None),
-        w_in=P(ep_spec, None, tp_spec),
-        w_gate=P(ep_spec, None, tp_spec) if w_gate is not None else None,
-        w_out=P(ep_spec, tp_spec, None),
-        shared=({k: (P(tp_spec, None) if k == "w_out" else P(None, tp_spec))
-                 for k in shared} if shared is not None else None),
-        membership=jax.tree_util.tree_map(lambda _: P(), membership),
-    )
+    specs = moe_param_specs(dep, p)
+    specs["x"] = P(x_spec, None)
+    specs["membership"] = jax.tree_util.tree_map(lambda _: P(), membership)
     out_specs = (P(x_spec, None), P(), P())
-    from repro.launch.mesh import shard_map_portable
-    fn = shard_map_portable(
+    fn = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(specs["x"], specs["router"], specs["w_in"], specs["w_gate"],
-                  specs["w_out"], specs["shared"], specs["membership"]),
+        in_specs=(specs["x"], specs["router"], specs["w_in"],
+                  specs.get("w_gate"), specs["w_out"], specs.get("shared"),
+                  specs["membership"]),
         out_specs=out_specs,
-        check=False,
+        check_vma=False,
     )
     y, load, dropped = fn(x, p["router"], p["w_in"], w_gate, p["w_out"],
                           shared, membership)

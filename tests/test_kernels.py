@@ -76,6 +76,26 @@ def test_gmm_sweep(sizes):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL32)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("sizes", [[5, 17, 0, 30], [40, 0, 0, 9]])
+def test_gmm_tiled_output_width(sizes, dtype):
+    """Several output-width blocks (block_f < f, f not a block multiple)
+    with boundary row blocks shared by two groups; bf16 rows round the row
+    block up to the 16-row sublane packing."""
+    G, d, f = len(sizes), 64, 80
+    T = int(sum(sizes))
+    key = jax.random.key(T + f)
+    x = jax.random.normal(key, (T, d), jnp.float32).astype(dtype)
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (G, d, f))
+         / np.sqrt(d)).astype(dtype)
+    got = gmm(x, w, jnp.asarray(sizes), block_t=16, block_k=32, block_f=32,
+              interpret=True)
+    want = ref.gmm_ref(x, w, jnp.asarray(sizes))
+    tol = TOL if dtype == jnp.bfloat16 else TOL32
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol)
+
+
 def test_gmm_receive_buffer_slack_rows():
     """Rows past sum(group_sizes) (static receive-buffer slack in the ragged
     dispatch) are unspecified but must not corrupt the real rows."""

@@ -7,7 +7,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import eplb_place, make_initial_membership, plan_repair
 from repro.core.backup import BackupStore
 from repro.core.placement import placement_overlap
-from repro.core.repair import apply_repair, tier2_gather_indices
+from repro.core.repair import apply_repair
 
 import jax
 import jax.numpy as jnp

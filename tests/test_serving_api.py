@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core import make_initial_membership
 from repro.core.reintegration import WarmupCostModel
-from repro.core.scenarios import list_scenarios
+from repro.core.scenarios import get_scenario, list_scenarios
 from repro.models import init_params
 from repro.runtime.elastic import ElasticEPRuntime
 from repro.runtime.scenario_runner import run_scenario
@@ -437,6 +437,11 @@ def test_stream_invariants_hold_across_registry_property():
             (name, mode, seed, res.stream_violations[:3])
         ev = res.client["events"]
         assert set(ev) <= set(EVENT_KINDS)
+        if get_scenario(name).expect_coverage_loss:
+            # graceful degradation: work needing the lost experts fails
+            # terminally with structured events (stream contract intact)
+            assert res.client["error_events"] > 0, (name, mode, seed)
+            return
         # elastic continuation: a fault or planned transition never shows
         # the client an error event
         assert res.client["error_events"] == 0, (name, mode, seed)
