@@ -260,26 +260,9 @@ def _run_group(cfg: ArchConfig, group: ScanGroup, gparams, x, *, mode: str,
             h = norm_apply(cfg.norm, xx, lp["norm1"])
             # ---- mixer ----
             if spec.mixer == "attn":
-                if mode == "train":
-                    y = (attn.mla_full(cfg, lp["attn"], h, positions)
-                         if cfg.attention == "mla"
-                         else attn.gqa_full(cfg, lp["attn"], h, positions))
-                    nc = {}
-                elif mode == "prefill":
-                    if cfg.attention == "mla":
-                        y, nc = attn.mla_prefill_cache(cfg, lp["attn"], h,
-                                                       positions, lc)
-                    else:
-                        y, nc = attn.gqa_prefill_cache(cfg, lp["attn"], h,
-                                                       positions, lc, i)
-                else:  # decode
-                    if cfg.attention == "mla":
-                        y, nc = attn.mla_decode(cfg, lp["attn"], h, lengths, lc)
-                    elif dpl.seq_shard_axis:
-                        y, nc = _seqsharded_decode(cfg, lp["attn"], h, lengths,
-                                                   lc, dpl)
-                    else:
-                        y, nc = attn.gqa_decode(cfg, lp["attn"], h, lengths, lc)
+                y, nc = _attention(cfg, spec, i, lp["attn"], h, mode=mode,
+                                   positions=positions, lengths=lengths,
+                                   lc=lc, dpl=dpl)
             elif spec.mixer == "mamba":
                 st = None if mode == "train" else lc
                 y, nc = mamba_apply(cfg, lp["mamba"], h, st,
@@ -375,6 +358,27 @@ def _run_group(cfg: ArchConfig, group: ScanGroup, gparams, x, *, mode: str,
     return x, new_caches, load
 
 
+@jax.named_scope("attention")
+def _attention(cfg: ArchConfig, spec: LayerSpec, i: int, p, h, *, mode: str,
+               positions, lengths, lc, dpl: Deployment):
+    """One attention mixer (QKV projections, the cache read and write,
+    attention, the output projection) under the device-trace scope
+    ``attention``. Returns (y, new layer cache)."""
+    if mode == "train":
+        y = (attn.mla_full(cfg, p, h, positions) if cfg.attention == "mla"
+             else attn.gqa_full(cfg, p, h, positions))
+        return y, {}
+    if mode == "prefill":
+        if cfg.attention == "mla":
+            return attn.mla_prefill_cache(cfg, p, h, positions, lc)
+        return attn.gqa_prefill_cache(cfg, p, h, positions, lc, i)
+    if cfg.attention == "mla":
+        return attn.mla_decode(cfg, p, h, lengths, lc)
+    if dpl.seq_shard_axis:
+        return _seqsharded_decode(cfg, p, h, lengths, lc, dpl)
+    return attn.gqa_decode(cfg, p, h, lengths, lc)
+
+
 def _seqsharded_decode(cfg, p, h, lengths, lc, dpl: Deployment):
     """Context-parallel decode island: cache sequence dim sharded over
     dpl.seq_shard_axis; LSE-merged partial attention."""
@@ -401,7 +405,9 @@ def _embed(cfg: ArchConfig, params, tokens):
     return jnp.take(params["embed"], tokens, axis=0)
 
 
+@jax.named_scope("lm_head")
 def _logits(cfg: ArchConfig, params, x):
+    """Final norm and LM head, under the device-trace scope ``lm_head``."""
     x = norm_apply(cfg.norm, x, params["final_norm"])
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)

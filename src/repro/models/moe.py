@@ -137,6 +137,7 @@ def slot_weight_keys(p) -> list[str]:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("moe.expert")
 def _expert_ffn(recv, w_in, w_gate, w_out, activation, tp_axes,
                 use_fused: bool = False):
     """recv: [spr, R, d]; w_*: [spr, d, de_local] / [spr, de_local, d].
@@ -183,6 +184,7 @@ def _grouped_matmul(x, w, group_sizes, dep: MoEDeployment):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+@jax.named_scope("moe.expert")
 def _expert_ffn_grouped(xg, group_sizes, w_in, w_gate, w_out, activation,
                         tp_axes, dep: MoEDeployment):
     """Ragged-path expert compute: xg [R, d] sorted by local slot with
@@ -235,27 +237,34 @@ def _moe_island(x, router, w_in, w_gate, w_out, shared, membership,
     else:
         token_ids = jnp.arange(T, dtype=jnp.int32)
 
-    logits = jnp.einsum("td,de->te", x, router) * m.router_scale
-    if fixed_s2e is not None:
-        experts, weights, slots = fixed_route(
-            logits, fixed_s2e, m.top_k, m.normalize_router_weights)
-    else:
-        experts, weights, slots = elastic_route(
-            logits, membership, m.top_k, token_ids,
-            m.normalize_router_weights)
+    # device-trace scopes: moe.route (router, replica choice through the
+    # membership), moe.exchange (dispatch and combine), and inside it
+    # moe.expert (the expert FFN)
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", x, router) * m.router_scale
+        if fixed_s2e is not None:
+            experts, weights, slots = fixed_route(
+                logits, fixed_s2e, m.top_k, m.normalize_router_weights)
+        else:
+            experts, weights, slots = elastic_route(
+                logits, membership, m.top_k, token_ids,
+                m.normalize_router_weights)
 
     inner_tp = () if (dep.defer_tp_reduce and dep.tp_axes) else dep.tp_axes
-    if dep.dispatch == "ragged":
-        grouped_fn = partial(_expert_ffn_grouped, w_in=w_in, w_gate=w_gate,
-                             w_out=w_out, activation=cfg.activation,
-                             tp_axes=inner_tp, dep=dep)
-        y, aux = dispatch_combine_ragged(x, slots, weights, grouped_fn, ep)
-    else:
-        expert_fn = partial(_expert_ffn, w_in=w_in, w_gate=w_gate,
-                            w_out=w_out, activation=cfg.activation,
-                            tp_axes=inner_tp, use_fused=dep.use_fused_ffn)
-        y, aux = dispatch_combine_dense(x, slots, weights,
-                                        lambda r: expert_fn(r), ep)
+    with jax.named_scope("moe.exchange"):
+        if dep.dispatch == "ragged":
+            grouped_fn = partial(_expert_ffn_grouped, w_in=w_in,
+                                 w_gate=w_gate, w_out=w_out,
+                                 activation=cfg.activation, tp_axes=inner_tp,
+                                 dep=dep)
+            y, aux = dispatch_combine_ragged(x, slots, weights, grouped_fn,
+                                             ep)
+        else:
+            expert_fn = partial(_expert_ffn, w_in=w_in, w_gate=w_gate,
+                                w_out=w_out, activation=cfg.activation,
+                                tp_axes=inner_tp, use_fused=dep.use_fused_ffn)
+            y, aux = dispatch_combine_dense(x, slots, weights,
+                                            lambda r: expert_fn(r), ep)
     if dep.defer_tp_reduce and dep.tp_axes:
         # SSPerf P1: TP partial sums ride the combine a2a and reduce here on
         # [T_local, d] — k*cf-times less psum volume than inside the expert

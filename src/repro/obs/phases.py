@@ -53,14 +53,27 @@ replan/repair-transfer (cascade composition), a rank may restart warmup
 rejoined — and warmups of different ranks overlap freely: they are
 background work.
 
+Two clocks. Span and event times (``t_start``/``t_end``, ``t``) are read
+from the clock the recorder is given (the runtime passes ``SimClock.now``):
+the modelled seconds every report, check and test reads. Beside them,
+``wall_start``/``wall_end`` (``wall_t`` on events) are read from
+``time.perf_counter``: the wall time of the real work the program did
+(slot moves, membership publication). A synchronous span and a marker
+also put ``repro.phase.<phase>`` on the profiler's timeline
+(``repro.obs.trace``), so recovery work shows on the same device trace
+as the serve step.
+
 Dependency-free on purpose: the CI lint job runs the report selftest with
 nothing installed beyond the standard library.
 """
 from __future__ import annotations
 
+import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Optional
+
+from repro.obs import trace
 
 #: Canonical recovery phases, in lifecycle order (see module docstring and
 #: docs/recovery-lifecycle.md — keep the two in sync).
@@ -119,6 +132,8 @@ class PhaseSpan:
     step_end: int = 0
     active_fraction: float = 1.0     # sampled when the span closes
     meta: dict = field(default_factory=dict)
+    wall_start: float = 0.0          # perf_counter seconds of the work
+    wall_end: float = _OPEN
 
     @property
     def open(self) -> bool:
@@ -152,6 +167,7 @@ class ObsEvent:
     step: int
     active_fraction: float
     detail: dict = field(default_factory=dict)
+    wall_t: float = 0.0              # perf_counter seconds
 
     def to_dict(self) -> dict:
         return {"t": round(self.t, 6), "kind": self.kind,
@@ -174,7 +190,8 @@ class PhaseClock:
         across many serving steps.
 
     The clock is any ``() -> float`` (the runtime passes ``SimClock.now``),
-    so the same recorder works under simulated or wall time.
+    so the same recorder works under simulated or wall time; the
+    wall-clock fields are ``time.perf_counter`` seconds.
     """
 
     def __init__(self, now: Callable[[], float], *,
@@ -230,11 +247,13 @@ class PhaseClock:
                         floor = max(floor, s.t_end)
             t_start = min(now, max(float(t_start), floor))
         sp = PhaseSpan(incident=incident, phase=phase, t_start=t_start,
-                       step_start=self.step, meta=meta)
+                       step_start=self.step, meta=meta,
+                       wall_start=time.perf_counter())
         self.spans.append(sp)
         return sp
 
     def _close(self, sp: PhaseSpan, extra: dict) -> PhaseSpan:
+        sp.wall_end = time.perf_counter()
         sp.t_end = self.now()
         sp.step_end = self.step
         sp.active_fraction = float(self.sample_active())
@@ -252,7 +271,8 @@ class PhaseClock:
         sp = self._new_span(phase, incident, meta, t_start=t_start)
         self._stack.append(sp)
         try:
-            yield sp
+            with trace.span("phase." + phase):
+                yield sp
         finally:
             self._stack.pop()
             self._close(sp, {})
@@ -272,6 +292,8 @@ class PhaseClock:
     def mark(self, phase: str, incident: int, **meta) -> PhaseSpan:
         """An instantaneous marker span (t_end == t_start)."""
         sp = self._new_span(phase, incident, meta)
+        with trace.span("phase." + phase):
+            pass
         return self._close(sp, {})
 
     def finalize(self) -> None:
@@ -303,7 +325,7 @@ class PhaseClock:
         ev = ObsEvent(t=self.now(), kind=_kind, incident=inc,
                       phase=self.current_phase(), step=self.step,
                       active_fraction=float(self.sample_active()),
-                      detail=detail)
+                      detail=detail, wall_t=time.perf_counter())
         self.events.append(ev)
         return ev
 

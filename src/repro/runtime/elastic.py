@@ -454,6 +454,7 @@ class ElasticEPRuntime:
                         self.record("recovery_restart", _incident=incident,
                                     ranks=sorted(pending), round=rounds)
                         continue
+                    txn.commit()    # publish the shrunken peer set
                     break
 
                 # expert-coverage repair: EPLB over survivors + 3-tier plan
@@ -514,14 +515,18 @@ class ElasticEPRuntime:
                         tier3_bytes=plan.tier3_bytes,
                         tier2_experts=[int(s2e[d]) for d, _ in plan.tier2],
                         tier3_experts=[int(e) for _, e in plan.tier3])
-                txn.apply()     # aborts if the plan lost experts
+                    txn.apply()     # aborts if the plan lost experts
+                    if not pending:
+                        # graph-visible routing repair: validate + publish
+                        # the staged configuration (content patch; bumps
+                        # the epoch). The slot moves land on the device
+                        # here, so the span's wall time is their real work
+                        # (the SimClock charged the model's)
+                        txn.commit()
                 if pending:
                     continue
                 break
 
-            # graph-visible routing repair: validate + publish the staged
-            # configuration (content patch; bumps the epoch)
-            txn.commit()
             # split-brain fencing: for casualties that may in fact still be
             # alive (false suspicion, network partition) the commit's epoch
             # bump IS the fence — any write they attempt against the old

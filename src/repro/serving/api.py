@@ -38,6 +38,7 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from repro.obs.trace import span as trace_span
 from repro.serving.events import (
     ERROR_KINDS,
     EVENT_KINDS,
@@ -264,9 +265,13 @@ class ServingFrontend:
 
     def step(self) -> int:
         """One engine iteration through the gateway: fire scheduled admin
-        transitions whose time has come, expire deadlines, then step."""
-        self._pump_admin()
-        return self.engine.step()
+        transitions whose time has come, expire deadlines, then step
+        (profiler spans ``repro.frontend.step`` around the whole and
+        ``repro.frontend.admin`` around the first two)."""
+        with trace_span("frontend.step"):
+            with trace_span("frontend.admin"):
+                self._pump_admin()
+            return self.engine.step()
 
     def run(self, *, until: Optional[float] = None,
             max_steps: int = 10_000) -> None:

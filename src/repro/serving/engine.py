@@ -39,6 +39,7 @@ from repro.core.transitions import (
 )
 from repro.launch.steps import make_serve_step
 from repro.models.model import init_caches
+from repro.obs.trace import span as trace_span
 from repro.runtime.elastic import ControlSummary, ElasticEPRuntime
 from repro.serving.kv_cache import make_pool
 from repro.serving.scheduler import Scheduler
@@ -250,7 +251,33 @@ class ServingEngine:
         return tokens, lengths
 
     def step(self) -> int:
-        """One engine iteration. Returns tokens produced."""
+        """One engine iteration. Returns tokens produced. Each stage runs
+        under its own profiler span (``repro.engine.<stage>``,
+        ``repro.obs.trace``), so a device trace puts the host's share of
+        the step down to the stage that took it."""
+        rt = self.rt
+        with trace_span("engine.control"):
+            self._control()
+        with trace_span("engine.admit"):
+            self._admit()
+        active = self.kv.active_slots()
+        if not active:
+            rt.clock.advance(self.base_step_time)
+            rt.heartbeat()
+            return 0
+        with trace_span("engine.inputs"):
+            tokens, lengths = self._build_inputs()
+            next_tok, _, self.caches = self._step(
+                rt.params, self.caches, rt.membership,
+                jnp.asarray(tokens), jnp.asarray(lengths))
+        with trace_span("engine.sync"):
+            next_tok = np.asarray(next_tok)
+        with trace_span("engine.bookkeep"):
+            return self._bookkeep(active, next_tok)
+
+    def _control(self) -> None:
+        """Pump the control plane: faults, drains, joins, rebalances, and
+        straggler mitigation, each with its effect on in-flight work."""
         rt = self.rt
         rt.obs.tick()      # telemetry: events/spans carry the step index
         # --- fault handling (between forward passes, paper §3.1): one pump
@@ -335,8 +362,12 @@ class ServingEngine:
             rt.observe_step_latencies(self.base_step_time)
             rt.mitigate_stragglers()
 
-        # --- admit into free slots: resumes validate their continuation
-        # snapshot against the device-published membership epoch ---
+    def _admit(self) -> None:
+        """Admit queued work into free slots (resumes validate their
+        continuation snapshot against the device-published membership
+        epoch), reset the fresh slots' cache rows, and apply pending page
+        moves."""
+        rt = self.rt
         admitted = self.sched.admit(now=rt.clock.now(),
                                     epoch=int(np.asarray(rt.membership.version)))
         if admitted:
@@ -352,14 +383,15 @@ class ServingEngine:
                 else:
                     mask[req.slot] = True
                     fresh = True
+                    # the pool allocated the slot with the whole context
+                    # resident; replay starts at the prefix skip (0 with
+                    # no hit), so rewind the resident length to match, or
+                    # the first replayed token would land past the end of
+                    # the context. On a prefix hit positions [0, skip)
+                    # arrive via the queued donor-row gather below
+                    # (applied AFTER the reset, so the copy lands clean).
                     skip = req.prefix_skip
-                    if skip > 0:
-                        # prefix hit: positions [0, skip) arrive via the
-                        # queued donor-row gather below (applied AFTER the
-                        # reset, so the copy lands clean); replay starts
-                        # at the skip position with the resident length
-                        # rewound to match
-                        self.kv.set_length(req.slot, skip)
+                    self.kv.set_length(req.slot, skip)
                     self._prompt_pos[req.slot] = skip
             if fresh:
                 self.caches = self._reset_slots(self.caches,
@@ -376,26 +408,17 @@ class ServingEngine:
             self.caches = self._gather_slots(self.caches,
                                              jnp.asarray(src, jnp.int32))
 
-        active = self.kv.active_slots()
-        if not active:
-            rt.clock.advance(self.base_step_time)
-            rt.heartbeat()
-            return 0
-
-        tokens, lengths = self._build_inputs()
-        next_tok, logits, self.caches = self._step(
-            rt.params, self.caches, rt.membership,
-            jnp.asarray(tokens), jnp.asarray(lengths))
-        next_tok = np.asarray(next_tok)
-
-        # --- bookkeeping: prefill replay vs real decode. ``replay_len``
-        # covers the prompt plus, on a continuation resume, the preserved
-        # generated prefix: replayed positions rebuild KV state without
-        # re-emitting tokens, so the client stream stays exactly-once.
-        # The throughput trace still counts re-decoded prefix positions —
-        # that is real decode-rate work (the retry baseline regenerates
-        # and counts the same tokens), only the client-facing delivery is
-        # deduplicated. ---
+    def _bookkeep(self, active, next_tok) -> int:
+        """Advance each slot's replay or deliver its token, then the load
+        EMA, the modelled step latency and the throughput sample."""
+        rt = self.rt
+        # prefill replay vs real decode: ``replay_len`` covers the prompt
+        # plus, on a continuation resume, the preserved generated prefix:
+        # replayed positions rebuild KV state without re-emitting tokens,
+        # so the client stream stays exactly-once. The throughput trace
+        # still counts re-decoded prefix positions — that is real
+        # decode-rate work (the retry baseline regenerates and counts the
+        # same tokens), only the client-facing delivery is deduplicated.
         produced = {}
         redecoded = 0
         resume_replaying = False

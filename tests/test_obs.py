@@ -503,3 +503,207 @@ def test_phases_constant_shape():
     assert PHASES == ("detect", "replan", "repair-transfer", "warmup",
                       "table-patch", "rejoin")
     assert set(PHASES) < set(ALL_PHASES)
+
+
+# ---------------------------------------------------------------------------
+# Two clocks (SimClock + wall) and the profiler's timeline
+# ---------------------------------------------------------------------------
+
+#: the keys a span serializes to: SimClock times only, as before the wall
+#: clock came (reports and scenario artifacts stay deterministic)
+SPAN_KEYS = {"incident", "phase", "t_start", "t_end", "duration_s",
+             "step_start", "step_end", "active_fraction", "meta"}
+
+
+def test_phaseclock_wall_times_monotonic_and_nested():
+    clk = FakeClock()
+    pc = PhaseClock(clk.now)
+    inc = pc.incident("failure", ranks=[3])
+    with pc.span("detect", inc) as detect:
+        clk.advance(1.0)
+        ev = pc.emit("failure", ranks=[3])
+        with pc.span("replan", inc) as replan:
+            clk.advance(0.5)
+    pc.open_span(("warmup", 3), "warmup", incident=inc, rank=3)
+    clk.advance(2.0)
+    pc.close_span(("warmup", 3))
+    pc.mark("rejoin", inc, rank=3)
+    # SimClock fields as before
+    assert (detect.t_start, detect.t_end) == (0.0, 1.5)
+    assert (replan.t_start, replan.t_end) == (1.0, 1.5)
+    assert ev.t == 1.0
+    # wall fields: closed, monotonic in recording order, children inside
+    walls = [(s.wall_start, s.wall_end) for s in pc.spans]
+    assert all(0 < a <= b for a, b in walls)
+    assert [a for a, _ in walls] == sorted(a for a, _ in walls)
+    assert detect.wall_start <= ev.wall_t <= replan.wall_start
+    assert replan.wall_end <= detect.wall_end
+    assert pc.spans[-1].wall_start >= pc.spans[-2].wall_end
+    assert all(set(s.to_dict()) == SPAN_KEYS for s in pc.spans)
+    assert "wall_t" not in ev.to_dict()
+
+
+def test_obs_records_with_jax_absent():
+    """The no-op path: with jax unimportable, repro.obs still records
+    spans on both clocks, and a profiler span does nothing."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+        import sys
+        sys.modules["jax"] = None            # `import jax` raises
+        from repro.obs import trace
+        from repro.obs.phases import PhaseClock, validate_spans
+        t = [0.0]
+        pc = PhaseClock(lambda: t[0])
+        inc = pc.incident("failure", ranks=[0])
+        with pc.span("detect", inc):
+            t[0] = 1.0
+        pc.mark("rejoin", inc, rank=0)
+        with trace.span("engine.step"):
+            pass
+        assert validate_spans(pc.spans) == []
+        assert all(0 < s.wall_start <= s.wall_end for s in pc.spans)
+        assert [s.t_end for s in pc.spans] == [1.0, 1.0]
+        print("ok")
+    """)
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=dict(os.environ, PYTHONPATH=str(src)),
+                         timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
+def test_scenario_spans_simclock_and_validation_unchanged(cascade_result):
+    """The wall clock leaves the SimClock record alone: a registry scenario
+    serializes the same spans on a second run, in the same keys, and they
+    validate as before."""
+    from repro.runtime.scenario_runner import run_scenario
+    again = run_scenario("cascade_mid_recovery")
+    assert again.spans == cascade_result.spans
+    assert all(set(s) == SPAN_KEYS for s in again.spans)
+    assert validate_spans(again.spans) == []
+    assert again.phase_totals == cascade_result.phase_totals
+
+
+def _host_events(trace_dir) -> list:
+    """(name, start ns, end ns) of every host event of the trace."""
+    import pathlib
+
+    from jax.profiler import ProfileData
+    (path,) = pathlib.Path(trace_dir).rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in pd.planes if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events]
+
+
+#: how long each slot move of the traced fault is made to take
+SLOW_MOVES_S = 0.05
+
+
+@pytest.fixture(scope="module")
+def traced_fault(tmp_path_factory):
+    """A tiny elastic instance served under the profiler: requests in
+    flight, a two-rank fault, its repair and the ranks' rejoin. Each device
+    slot move is slowed by ``SLOW_MOVES_S`` and its wall interval kept,
+    with a ``test.slot_moves`` span of its own on the profiler."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.core import make_initial_membership
+    from repro.core import transitions
+    from repro.core.reintegration import WarmupCostModel
+    from repro.models import init_params
+    from repro.runtime.elastic import ElasticEPRuntime
+    from repro.serving.api import ServingFrontend
+    from repro.serving.engine import ServingEngine
+
+    moves = []
+    real_moves = transitions.apply_slot_moves
+
+    def slow_moves(*a, **kw):
+        with jax.profiler.TraceAnnotation("test.slot_moves"):
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(real_moves(*a, **kw))
+            time.sleep(SLOW_MOVES_S)
+            moves.append((t0, time.perf_counter()))
+        return out
+
+    cfg = get_config("mixtral-8x22b").reduced()
+    table = make_initial_membership(8, cfg.moe.num_experts, 1)
+    params = init_params(cfg, jax.random.key(0), jnp.float32,
+                         table.slot_to_expert, table.num_slots)
+    rt = ElasticEPRuntime(cfg, params, table,
+                          warmup_model=WarmupCostModel(1, 1, 2, 1))
+    eng = ServingEngine(rt, max_batch=4, max_len=64)
+    fe = ServingFrontend(eng)
+    handles = [fe.submit([3, 1, 4, 1, 5], max_new=30) for _ in range(3)]
+    fe.step()                                  # compile outside the trace
+    out = tmp_path_factory.mktemp("trace")
+    with pytest.MonkeyPatch.context() as mp, jax.profiler.trace(str(out)):
+        mp.setattr(transitions, "apply_slot_moves", slow_moves)
+        fe.step()
+        # both replicas of expert 3: its repair has to move weights
+        rt.injector.inject_at(rt.clock.now(), [3, 7])
+        for _ in range(2000):
+            fe.step()
+            if all(h.done for h in handles) and any(
+                    s.phase == "rejoin" for s in rt.obs.spans):
+                break
+    return rt, handles, _host_events(out), moves
+
+
+def test_engine_step_spans_in_profiler_trace(traced_fault):
+    _, handles, events, _ = traced_fault
+    names = {n for n, _, _ in events}
+    assert all(h.outcome == "FINISHED" for h in handles)
+    for stage in ("control", "admit", "inputs", "sync", "bookkeep"):
+        assert f"repro.engine.{stage}" in names
+    assert {"repro.frontend.step", "repro.frontend.admin"} <= names
+
+
+def test_recorded_fault_phases_on_both_clocks_and_the_profiler(traced_fault):
+    rt, _, events, _ = traced_fault
+    names = {n for n, _, _ in events}
+    spans = rt.obs.spans
+    phases = {s.phase for s in spans}
+    assert {"detect", "replan", "repair-transfer", "warmup", "table-patch",
+            "rejoin"} <= phases
+    assert validate_spans(spans) == []
+    for s in spans:
+        assert s.t_end >= s.t_start >= 0.0             # SimClock
+        assert 0 < s.wall_start <= s.wall_end          # wall clock
+    # every synchronous span and marker is on the profiler's timeline; a
+    # warmup runs in the background across steps and is not
+    for phase in phases - {"warmup"}:
+        assert f"repro.phase.{phase}" in names
+    assert "repro.phase.warmup" not in names
+    assert all(ev.wall_t > 0 for ev in rt.obs.events)
+
+
+def test_slot_moves_inside_a_phase_span_on_both_clocks(traced_fault):
+    """The recovery's real work, the device slot moves of the fault's
+    repair and of the rejoin, runs inside a phase span: within the wall
+    interval of its ``PhaseSpan`` and under a ``repro.phase.*`` span on
+    the profiler's timeline."""
+    rt, _, events, moves = traced_fault
+    assert moves
+    inside = set()
+    for t0, t1 in moves:
+        (sp,) = [s for s in rt.obs.spans if s.phase != "warmup"
+                 and s.wall_start <= t0 and t1 <= s.wall_end]
+        inside.add(sp.phase)
+    assert {"repair-transfer", "table-patch"} <= inside
+    phase_spans = [(s, e) for n, s, e in events
+                   if n.startswith("repro.phase.")]
+    marked = [(s, e) for n, s, e in events if n == "test.slot_moves"]
+    assert len(marked) == len(moves)
+    for s, e in marked:
+        assert any(ps <= s and e <= pe for ps, pe in phase_spans)
