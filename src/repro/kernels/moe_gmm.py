@@ -145,8 +145,14 @@ def _gmm_metadata(group_sizes, bt: int, nblocks: int):
     return tile_group, tile_block, starts, ends
 
 
-def _gmm_kernel(tg_ref, tb_ref, gs_ref, ge_ref, x_ref, w_ref, o_ref, acc_ref,
-                *, bt: int):
+def _sublane_rows(dtype) -> int:
+    """Least legal row-block multiple: 8 rows of 32 bits per sublane tile,
+    so 16 for bf16 and 32 for 8-bit types (packed rows)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _gmm_tiled_kernel(tg_ref, tb_ref, gs_ref, ge_ref, x_ref, w_ref, o_ref,
+                      acc_ref, *, bt: int):
     i = pl.program_id(1)
     k = pl.program_id(2)
 
@@ -172,56 +178,18 @@ def _gmm_kernel(tg_ref, tb_ref, gs_ref, ge_ref, x_ref, w_ref, o_ref, acc_ref,
                                ).astype(o_ref.dtype)
 
 
-def _sublane_rows(dtype) -> int:
-    """Least legal row-block multiple: 8 rows of 32 bits per sublane tile,
-    so 16 for bf16 and 32 for 8-bit types (packed rows)."""
-    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-
-
-def gmm(x, w, group_sizes, *, block_t: int = 128, block_k: int = 512,
-        block_f: int = 1024, interpret: bool = False):
-    """Grouped matmul over group-sorted rows: the first ``group_sizes[0]``
-    rows of x [T, d] belong to group 0, and so on; w [G, d, f].
-
-    ``group_sizes`` may be traced, contain zeros, and need not be multiples
-    of ``block_t`` — boundary row blocks are revisited once per overlapping
-    group with a row mask, so ragged dispatch needs NO per-group padding.
-    Rows beyond ``sum(group_sizes)`` (receive-buffer slack) produce
-    unspecified output; callers must never read them. Returns [T, f].
-
-    Grid (f-blocks, logical tiles, k-blocks): the output width is tiled so
-    the weight block (1, bk, bf) and the fp32 accumulator (bt, bf) stay in
-    the default scoped VMEM at any expert width. The f axis is OUTERMOST so
-    that, for each output column block, the revisits of a boundary row
-    block by successive tiles stay consecutive (the blend-store reads the
-    rows the previous group's visit left in the resident output block)."""
-    T, d = x.shape
-    G, _, f = w.shape
-    if T == 0:
-        return jnp.zeros((0, f), x.dtype)
-    sub = _sublane_rows(x.dtype)
-    bt = min(block_t, -(-T // sub) * sub)
-    pad_t = (-T) % bt
-    if pad_t:
-        x = jnp.pad(x, ((0, pad_t), (0, 0)))
-    nblocks = (T + pad_t) // bt
-
-    bk = min(block_k, d)
-    pad_k = (-d) % bk
-    if pad_k:
-        x = jnp.pad(x, ((0, 0), (0, pad_k)))
-        w = jnp.pad(w, ((0, 0), (0, pad_k), (0, 0)))
-    dp = d + pad_k
-
-    bf = min(block_f, f)
-    pad_f = (-f) % bf
-    if pad_f:
-        w = jnp.pad(w, ((0, 0), (0, 0), (0, pad_f)))
-    fp = f + pad_f
-
+def _gmm_tiled(x, w, group_sizes, *, bt: int, bk: int, bf: int,
+               interpret: bool):
+    """Grid (f-blocks, logical tiles, k-blocks) over row blocks of ``bt``:
+    the row block and the weight block are both fetched on every step, so
+    a group straddling a row-block boundary streams its weights once per
+    block. For calls whose rows do not fit VMEM (large T)."""
+    T, dp = x.shape
+    G, _, fp = w.shape
+    nblocks = T // bt
     tile_group, tile_block, starts, ends = _gmm_metadata(group_sizes, bt,
                                                          nblocks)
-    kernel = functools.partial(_gmm_kernel, bt=bt)
+    kernel = functools.partial(_gmm_tiled_kernel, bt=bt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(fp // bf, nblocks + G - 1, dp // bk),
@@ -234,10 +202,176 @@ def gmm(x, w, group_sizes, *, block_t: int = 128, block_k: int = 512,
                                lambda j, i, k, tg, tb, gs, ge: (tb[i], j)),
         scratch_shapes=[pltpu.VMEM((bt, bf), jnp.float32)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T + pad_t, fp), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, fp), x.dtype),
         interpret=interpret,
+        name="gmm_tiled",
     )(tile_group, tile_block, starts, ends, x, w)
+
+
+# Rows resident in VMEM. The resident schedule holds all of x (one buffer),
+# the [T, bf] output block (two buffers), its fp32 accumulator and the
+# weight block (two buffers). Sized for Mixtral-8x22B's w_out call at 256
+# rows (128 decode rows x top-2), the largest the serve step makes: x
+# 256 x 16384 x 2 B = 8 MiB, output 2 x 0.5 MiB, accumulator 1 MiB,
+# weights 2 x 1 MiB = 12 MiB; with the window's temporaries that call
+# compiles to 13.9 MiB of v5e's 16 MiB default scoped VMEM.
+_RESIDENT_VMEM_BYTES = 12 * 2**20
+# Rows multiplied at a time in the resident schedule, a multiple of every
+# dtype's sublane rows. The MXU's time grows with a window's rows and has
+# to hide under each step's 1 MiB weight DMA (~1.3 us on v5e): at
+# Mixtral-8x22B's widths and ~16-row groups a call took 4.31 ms with
+# 32-row windows, 4.41 with 64 and 4.85 with 128; 16-row ones need two
+# windows for most groups.
+_WINDOW_ROWS = 32
+
+
+def _resident_vmem_bytes(rows: int, dp: int, bk: int, bf: int,
+                         itemsize: int) -> int:
+    return (rows * dp * itemsize + 2 * rows * bf * itemsize + rows * bf * 4
+            + 2 * bk * bf * itemsize)
+
+
+def _resident_metadata(group_sizes, nk: int):
+    """Scalar-prefetch operands of the resident schedule: each group's row
+    range, and the weight block (group, k) its steps fetch. A non-empty
+    group fetches its own blocks; an empty group repeats the block of the
+    step before it (the last non-empty group's last k-block, or, ahead of
+    every non-empty group, the first one's first), so no DMA is issued."""
+    G = group_sizes.shape[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    live = sizes > 0
+    prev = jax.lax.cummax(jnp.where(live, jnp.arange(G, dtype=jnp.int32),
+                                    -1))
+    first = jnp.argmax(live).astype(jnp.int32)
+    w_group = jnp.where(prev >= 0, prev, first)
+    # the k-block an empty group's steps fetch; -1: each step's own k
+    w_k = jnp.where(live, -1, jnp.where(prev >= 0, nk - 1, 0))
+    return starts, ends, w_group, w_k.astype(jnp.int32)
+
+
+def _resident_grid_spec(rows: int, dp: int, G: int, fp: int, bk: int,
+                        bf: int):
+    """Grid (f-blocks j, groups g, k-blocks): x is one block fetched once
+    per call, each non-empty group's weight block (g, k, j) is fetched
+    once, and the [rows, bf] output block is written once per j."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(fp // bf, G, dp // bk),
+        in_specs=[
+            pl.BlockSpec((rows, dp), lambda j, g, k, *_: (0, 0),
+                         pipeline_mode=pl.Buffered(1)),
+            pl.BlockSpec((1, bk, bf), lambda j, g, k, gs, ge, wg, wk: (
+                wg[g], jnp.where(wk[g] < 0, k, wk[g]), j)),
+        ],
+        out_specs=pl.BlockSpec((rows, bf), lambda j, g, k, *_: (0, j)),
+        scratch_shapes=[pltpu.VMEM((rows, bf), jnp.float32)],
+    )
+
+
+def _gmm_resident_kernel(gs_ref, ge_ref, wg_ref, wk_ref, x_ref, w_ref, o_ref,
+                         acc_ref, *, bk: int, window: int, sub: int):
+    g = pl.program_id(1)
+    k = pl.program_id(2)
+
+    @pl.when((g == 0) & (k == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    start, end = gs_ref[g], ge_ref[g]
+
+    @pl.when(end > start)
+    def _():
+        w = w_ref[0]                                # [bk, bf]
+        col = pl.multiple_of(k * bk, bk)
+        first = (start // sub) * sub
+        last = acc_ref.shape[0] - window            # the last window in x
+
+        def body(i, carry):
+            # window i covers rows [r, r + window); the last one is pulled
+            # back inside x, and the rows it shares with window i - 1 are
+            # masked out with the neighbouring groups' rows
+            r = first + i * window
+            c = pl.multiple_of(jnp.minimum(r, last), sub)
+            p = jnp.dot(x_ref[pl.ds(c, window), pl.ds(col, bk)], w,
+                        preferred_element_type=jnp.float32)
+            rows = c + jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
+            mask = (rows >= jnp.maximum(start, r)) & (rows < end)
+            acc_ref[pl.ds(c, window), :] += jnp.where(mask, p, 0.0)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(end - first, window), body, 0)
+
+    @pl.when((g == pl.num_programs(1) - 1) & (k == pl.num_programs(2) - 1))
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _gmm_resident(x, w, group_sizes, *, bk: int, bf: int, interpret: bool):
+    rows, dp = x.shape
+    G, _, fp = w.shape
+    sub = _sublane_rows(x.dtype)
+    kernel = functools.partial(_gmm_resident_kernel, bk=bk, sub=sub,
+                               window=min(_WINDOW_ROWS, rows))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=_resident_grid_spec(rows, dp, G, fp, bk, bf),
+        out_shape=jax.ShapeDtypeStruct((rows, fp), x.dtype),
+        interpret=interpret,
+        name="gmm_resident",
+    )(*_resident_metadata(group_sizes, dp // bk), x, w)
+
+
+def gmm(x, w, group_sizes, *, block_t: int = 128, block_k: int = 512,
+        block_f: int = 1024, interpret: bool = False):
+    """Grouped matmul over group-sorted rows: the first ``group_sizes[0]``
+    rows of x [T, d] belong to group 0, and so on; w [G, d, f].
+
+    ``group_sizes`` may be traced, contain zeros, and need not be multiples
+    of any block; ragged dispatch needs NO per-group padding. Rows beyond
+    ``sum(group_sizes)`` (receive-buffer slack) produce unspecified output;
+    callers must never read them. Returns [T, f].
+
+    Two schedules compute the same product, each row accumulated in fp32
+    over the k-blocks of ``block_k`` in order; the call's shapes choose:
+
+    - resident (``gmm_resident``), when x, the output block, the
+      accumulator and the weight block fit ``_RESIDENT_VMEM_BYTES``: x is
+      read once, each non-empty group's weights once, and each step
+      multiplies only its group's rows (the serve step's decode calls);
+    - tiled (``gmm_tiled``) otherwise: row blocks of ``block_t``, each
+      boundary block revisited once per overlapping group with a row mask,
+      so a straddling group's weights and every row block are streamed
+      again per visit (training, prefill, large EP receive buffers).
+
+    The output width is tiled by ``block_f`` so the weight block and the
+    accumulator stay in the default scoped VMEM at any expert width; the f
+    axis is outermost in both grids."""
+    T, d = x.shape
+    G, _, f = w.shape
+    if T == 0:
+        return jnp.zeros((0, f), x.dtype)
+    sub = _sublane_rows(x.dtype)
+    rows = -(-T // sub) * sub
+    bk = min(block_k, d)
+    bf = min(block_f, f)
+    pad_k, pad_f = (-d) % bk, (-f) % bf
+    if pad_k or pad_f:
+        w = jnp.pad(w, ((0, 0), (0, pad_k), (0, pad_f)))
+    resident = _resident_vmem_bytes(rows, d + pad_k, bk, bf,
+                                    x.dtype.itemsize) <= _RESIDENT_VMEM_BYTES
+    bt = min(block_t, rows)
+    pad_t = rows - T if resident else (-T) % bt
+    if pad_t or pad_k:
+        x = jnp.pad(x, ((0, pad_t), (0, pad_k)))
+    if resident:
+        out = _gmm_resident(x, w, group_sizes, bk=bk, bf=bf,
+                            interpret=interpret)
+    else:
+        out = _gmm_tiled(x, w, group_sizes, bt=bt, bk=bk, bf=bf,
+                         interpret=interpret)
     return out[:T, :f]

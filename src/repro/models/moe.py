@@ -66,6 +66,7 @@ class MoEDeployment:
     # interpret-mode Pallas is orders of magnitude slower than XLA and would
     # dominate simulation wall time).
     use_pallas_gmm: Optional[bool] = None
+    # Row block of gmm's tiled schedule (calls whose rows do not fit VMEM).
     gmm_block_t: int = 128
 
     @property

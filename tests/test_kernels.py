@@ -1,11 +1,13 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
+import itertools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import moe_gmm, ref
 from repro.kernels.flash_attention import (
     flash_attention_decode,
     flash_attention_prefill,
@@ -57,6 +59,18 @@ def test_fused_moe_ffn_sweep(S, R, d, de, act, gated, dtype):
                                np.asarray(want, np.float32), **tol)
 
 
+@pytest.fixture(params=["resident", "tiled"])
+def schedule(request, monkeypatch):
+    """Force one of gmm's two schedules whatever the call's shapes; the
+    resident one walks 16-row windows, so small cases take several."""
+    if request.param == "tiled":
+        monkeypatch.setattr(moe_gmm, "_RESIDENT_VMEM_BYTES", 0)
+    else:
+        monkeypatch.setattr(moe_gmm, "_RESIDENT_VMEM_BYTES", 2**62)
+        monkeypatch.setattr(moe_gmm, "_WINDOW_ROWS", 16)
+    return request.param
+
+
 @pytest.mark.parametrize("sizes", [
     # block-multiple sizes (the original contract)
     [64, 32, 0, 96], [32, 32, 32, 32], [0, 0, 128, 0],
@@ -64,7 +78,7 @@ def test_fused_moe_ffn_sweep(S, R, d, de, act, gated, dtype):
     [5, 17, 0, 30], [1, 0, 63], [0, 0, 0, 7], [3], [129, 31, 40],
     [31, 1, 1, 31],
 ])
-def test_gmm_sweep(sizes):
+def test_gmm_sweep(sizes, schedule):
     G, d, f = len(sizes), 64, 48
     T = int(sum(sizes))
     key = jax.random.key(T + G)
@@ -78,7 +92,7 @@ def test_gmm_sweep(sizes):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("sizes", [[5, 17, 0, 30], [40, 0, 0, 9]])
-def test_gmm_tiled_output_width(sizes, dtype):
+def test_gmm_tiled_output_width(sizes, dtype, schedule):
     """Several output-width blocks (block_f < f, f not a block multiple)
     with boundary row blocks shared by two groups; bf16 rows round the row
     block up to the 16-row sublane packing."""
@@ -96,7 +110,7 @@ def test_gmm_tiled_output_width(sizes, dtype):
                                np.asarray(want, np.float32), **tol)
 
 
-def test_gmm_receive_buffer_slack_rows():
+def test_gmm_receive_buffer_slack_rows(schedule):
     """Rows past sum(group_sizes) (static receive-buffer slack in the ragged
     dispatch) are unspecified but must not corrupt the real rows."""
     sizes = [10, 0, 12]
@@ -112,7 +126,7 @@ def test_gmm_receive_buffer_slack_rows():
                                **TOL32)
 
 
-def test_gmm_traced_group_sizes_under_jit():
+def test_gmm_traced_group_sizes_under_jit(schedule):
     """group_sizes may be a traced value (the size exchange's output): one
     compiled executable serves every load distribution."""
     d, f, G = 48, 32, 4
@@ -125,6 +139,80 @@ def test_gmm_traced_group_sizes_under_jit():
         got = fn(x, w, jnp.asarray(sizes))
         want = ref.gmm_ref(x, w, jnp.asarray(sizes))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL32)
+
+
+def test_gmm_one_group_holds_every_row(schedule):
+    """Every row in one group, several row blocks (and windows) long."""
+    sizes = [0, 300, 0]
+    d, f = 64, 48
+    key = jax.random.key(300)
+    x = jax.random.normal(key, (300, d), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, d, f)) / np.sqrt(d)
+    got = gmm(x, w, jnp.asarray(sizes), block_t=32, block_k=32,
+              interpret=True)
+    want = ref.gmm_ref(x, w, jnp.asarray(sizes))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL32)
+
+
+def test_gmm_bf16_groups_off_the_sublane_multiple(schedule):
+    """bf16 groups whose sizes and starts are not multiples of the 16-row
+    sublane packing: windows and row blocks shared by neighbours."""
+    sizes = [7, 23, 0, 41, 9, 1, 15]
+    G, d, f = len(sizes), 64, 48
+    T = int(sum(sizes))
+    key = jax.random.key(96)
+    x = jax.random.normal(key, (T, d), jnp.float32).astype(jnp.bfloat16)
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (G, d, f))
+         / np.sqrt(d)).astype(jnp.bfloat16)
+    got = gmm(x, w, jnp.asarray(sizes), block_t=16, block_k=32,
+              interpret=True)
+    want = ref.gmm_ref(x, w, jnp.asarray(sizes))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **TOL)
+
+
+@pytest.mark.parametrize("sizes", [
+    [0, 5, 0, 0, 17, 30, 0], [0, 0, 9], [12, 0, 0, 0], [3, 3, 3],
+])
+def test_gmm_resident_reads_weights_once_per_live_group(sizes):
+    """The resident schedule's index maps, evaluated over its grid: each
+    non-empty group's weight blocks are fetched once (a block index that
+    repeats the previous step's issues no DMA), empty groups fetch
+    nothing, and x is one block for the whole call."""
+    nk, nf = 3, 2
+    spec = moe_gmm._resident_grid_spec(rows=64, dp=32 * nk, G=len(sizes),
+                                       fp=16 * nf, bk=32, bf=16)
+    meta = [np.asarray(a) for a in
+            moe_gmm._resident_metadata(jnp.asarray(sizes), nk)]
+    x_spec, w_spec = spec.in_specs
+    steps = list(itertools.product(*map(range, spec.grid)))
+
+    def blocks(bspec):
+        return [tuple(int(v) for v in bspec.index_map(*s, *meta))
+                for s in steps]
+
+    w_blocks = blocks(w_spec)
+    fetches = 1 + sum(a != b for a, b in zip(w_blocks, w_blocks[1:]))
+    live = [g for g, n in enumerate(sizes) if n]
+    assert fetches == len(live) * nk * nf
+    assert set(w_blocks) == set(itertools.product(live, range(nk),
+                                                  range(nf)))
+    assert set(blocks(x_spec)) == {(0, 0)}
+
+
+def test_gmm_schedule_follows_shapes():
+    """Mixtral-8x22B's decode calls (256 rows) take the resident schedule
+    at both projections; a large receive buffer takes the tiled one."""
+    def kernel(rows, d_in, d_out):
+        x = jax.ShapeDtypeStruct((rows, d_in), jnp.bfloat16)
+        w = jax.ShapeDtypeStruct((16, d_in, d_out), jnp.bfloat16)
+        gs = jax.ShapeDtypeStruct((16,), jnp.int32)
+        text = str(jax.make_jaxpr(gmm)(x, w, gs))
+        return {n for n in ("gmm_resident", "gmm_tiled") if n in text}
+
+    for d_in, d_out in [(6144, 16384), (16384, 6144)]:
+        assert kernel(256, d_in, d_out) == {"gmm_resident"}
+        assert kernel(8192, d_in, d_out) == {"gmm_tiled"}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -82,7 +82,10 @@ def _one_layer():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("rows", [2 * BATCH, 128])   # decode: 8 tokens x top-2
+# decode: 8 tokens x top-2; 128 rows; the batch cell's 128 rows x top-2,
+# whose x fills the resident schedule's VMEM at w_out; a receive buffer too
+# large to keep resident
+@pytest.mark.parametrize("rows", [2 * BATCH, 128, 256, 8192])
 @pytest.mark.parametrize("proj", ["up", "down"])
 def test_gmm_compiles_at_mixtral_widths(one_chip, rows, proj):
     cfg = get_config(ARCH)
@@ -93,7 +96,10 @@ def test_gmm_compiles_at_mixtral_widths(one_chip, rows, proj):
                              sharding=one_chip)
     gs = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(gmm).lower(x, w, gs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernel = "gmm_tiled" if rows == 8192 else "gmm_resident"
+    assert len(calls) == 1 and f"%{kernel}" in calls[0]
 
 
 # ---------------------------------------------------------------------------
