@@ -8,8 +8,16 @@ Run from the root of a checkout on a machine that holds the chips the cell
 asks for. ``BENCHMARK.json`` at the root names the cell; the harness finds
 everything else under this directory by name:
 
-- ``configs/<config>.json``: the model at published widths, its cuts, the
-  deployment (EP ranks, slots, dispatch, dtype, batch, cache length);
+- ``configs/<config>.json``: the model at published widths under its
+  family's Hugging Face keys, its cuts, the deployment (EP ranks, the
+  ranks whose slots this chip holds, slots, dispatch, dtype, batch, cache
+  length), and the names of its adapter and its reference;
+- ``adapters/<adapter>.py``: the configuration onto the program:
+  ``arch_config(conf)`` (the program's ``ArchConfig``; ValueError for a
+  file the program cannot run), ``build(cfg, conf, seed)`` (the serving
+  instance: runtime, engine, frontend) and ``compile_parts(cfg, conf)``
+  (what ``compile_check.py`` compiles); the harness refuses an
+  ``ArchConfig`` whose widths differ from the file's;
 - ``traffic/<mix>.json``: the open-loop load or the closed batch
   (``loadgen.py``), the rank faults, the drain cap, the correctness sample
   and its limit;
@@ -18,8 +26,8 @@ everything else under this directory by name:
 - ``reference/<family>.py``: the plain float32 model that decides
   ``correct``; ``peaks.json``: the chip's peaks by ``device_kind``.
 
-A run builds the instance through ``repro.launch.serve.build_frontend``,
-warms every program the window calls (the serve step, the slot reset, the
+A run builds the instance through the configuration's adapter, warms
+every program the window calls (the serve step, the slot reset, the
 donor-row gather after a prefix fill, and one rank fault with its repair
 and rejoin in a fault cell), offers the load from ``preroll_s`` before the
 window opens, measures ``--seconds`` on the wall clock, follows every
@@ -66,7 +74,9 @@ ROOT = CHIP_DIR.parents[1]
 if str(CHIP_DIR) not in sys.path:
     sys.path.insert(0, str(CHIP_DIR))
 
+import flops  # noqa: E402
 import loadgen  # noqa: E402
+import scopes  # noqa: E402
 import xplane  # noqa: E402
 
 #: the traced slice: the window's last seconds, so that stopping the
@@ -135,37 +145,55 @@ def load_reference(chip_dir: pathlib.Path, family: str, model: dict,
     return mod.build(model, dtype)
 
 
-#: configuration keys the program fixes and cannot be told otherwise
-PROGRAM_FIXED = {"rms_norm_eps": 1e-6, "hidden_act": "silu",
-                 "tie_word_embeddings": False}
+def load_adapter(chip_dir: pathlib.Path, conf: dict):
+    """The module ``adapters/<conf["adapter"]>.py``."""
+    name = conf.get("adapter")
+    path = chip_dir / "adapters" / f"{name}.py"
+    if not name or not path.is_file():
+        raise Refused(f"{conf['name']}: no adapter {path}")
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench_adapter_{name.replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
-def arch_config(conf: dict):
-    """The program's ArchConfig for a configuration file."""
-    from repro.configs.base import ArchConfig, MoEArchConfig
+def built_widths(cfg, model: dict) -> dict:
+    """{file key: (the file's value, the ArchConfig's)} of every width the
+    harness holds an adapter to: hidden size, heads, head size where the
+    file gives one, vocabulary, depth, routed experts, experts per token
+    and the expert width, each under the key the file names it by."""
+    moe = cfg.moe
+    pairs = {"hidden_size": cfg.d_model,
+             "num_attention_heads": cfg.num_heads,
+             "vocab_size": cfg.vocab_size,
+             "num_hidden_layers": cfg.num_layers,
+             flops.expert_count_key(model) or "num_local_experts":
+                 moe and moe.num_experts,
+             "num_experts_per_tok": moe and moe.top_k,
+             flops.expert_width_key(model): moe and moe.d_expert}
+    if model.get("head_dim"):
+        pairs["head_dim"] = cfg.head_dim
+    return {k: (model.get(k), v) for k, v in pairs.items()}
 
-    m, dep = conf["model"], conf["deployment"]
-    for key, value in PROGRAM_FIXED.items():
-        if m.get(key) != value:
-            raise Refused(f"{conf['name']}: {key}={m.get(key)!r}, but the "
-                          f"program runs {value!r}")
-    window = m.get("sliding_window") or 0
-    return ArchConfig(
-        name=conf["name"], family="moe",
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim") or 0, d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"],
-        attention="swa" if window else "gqa", window=window,
-        activation="swiglu", rope_theta=float(m["rope_theta"]),
-        moe=MoEArchConfig(num_experts=m["num_local_experts"],
-                          top_k=m["num_experts_per_tok"],
-                          d_expert=m["intermediate_size"]),
-        slots_per_rank=dep["slots_per_rank"],
-        ranks_per_host=dep["ranks_per_host"],
-        hosts_per_switch=dep["hosts_per_switch"],
-        dispatch_mode=dep["dispatch"], kv_block_size=dep["kv_block_size"])
+
+def arch_config(conf: dict, adapter=None):
+    """The adapter's ArchConfig for a configuration file, refused where
+    the adapter cannot run the file or built other widths than it gives.
+    ``adapter`` is the module ``load_adapter`` gives; by default the one
+    under this directory that the file names, or ``mixtral`` for a file
+    that names none (the program's own tests build such files)."""
+    if adapter is None:
+        adapter = load_adapter(CHIP_DIR, dict({"adapter": "mixtral"}, **conf))
+    try:
+        cfg = adapter.arch_config(conf)
+    except ValueError as e:
+        raise Refused(f"{conf['name']}: {e}") from None
+    for key, (given, built) in built_widths(cfg, conf["model"]).items():
+        if given != built:
+            raise Refused(f"{conf['name']}: the adapter built {key}={built}"
+                          f", the file gives {given}")
+    return cfg
 
 
 # ---------------------------------------------------------------------------
@@ -268,26 +296,16 @@ class CellRun:
         import jax.numpy as jnp
         from jax import monitoring
 
-        from repro.core.topology import FaultDomainTree
-        from repro.launch.serve import build_frontend
-
+        adapter = load_adapter(self.chip_dir, self.conf)
+        self.cfg = arch_config(self.conf, adapter)
         kind = jax.devices()[0].device_kind
         if kind not in self.peaks and jax.devices()[0].platform == "tpu":
             raise Refused(f"no peaks for device_kind {kind!r} in peaks.json")
         self.run.peak = self.peaks.get(kind, {})
         monitoring.register_event_duration_secs_listener(self._on_event)
         gc.callbacks.append(self._on_gc)
-        dep = self.conf["deployment"]
-        self.cfg = arch_config(self.conf)
-        world = dep["ep_ranks"]
-        self.rt, self.eng, self.fe = build_frontend(
-            self.cfg, world=world, slots_per_rank=dep["slots_per_rank"],
-            topology=FaultDomainTree(world,
-                                     ranks_per_host=dep["ranks_per_host"],
-                                     hosts_per_switch=dep["hosts_per_switch"]),
-            seed=self.seed, dtype=jnp.dtype(dep["dtype"]),
-            dispatch=dep["dispatch"], max_batch=dep["max_batch"],
-            max_len=dep["max_len"])
+        self.rt, self.eng, self.fe = adapter.build(self.cfg, self.conf,
+                                                   self.seed)
         jax.block_until_ready(self.rt.params)
         if "closed" in self.mix:
             from repro.launch.steps import make_serve_step
@@ -768,6 +786,8 @@ class CellRun:
             "memory": run.memory,
         }
         if run.trace is not None:
+            # the serve step's stages with the unscoped rest and the op total
+            info["stage_ms"] = scopes.stage_ms(run.trace)
             info["trace_lines"] = run.trace.line_names
             info["trace_modules"] = sorted({n for n, _, _ in
                                             run.trace.modules})[:20]

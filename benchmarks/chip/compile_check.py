@@ -31,16 +31,15 @@ def main(name: str) -> None:
     from jax.sharding import SingleDeviceSharding
 
     import bench
-    from repro.core import make_initial_membership
     from repro.launch.steps import make_serve_step
     from repro.models import moe as moe_mod
-    from repro.models.model import Deployment, init_caches, init_params, \
-        param_shapes
+    from repro.models.model import init_params
 
     jax.config.update("jax_enable_compilation_cache", False)
     conf = json.loads((CHIP_DIR / "configs" / f"{name}.json").read_text())
     dep = conf["deployment"]
-    cfg = bench.arch_config(conf)
+    adapter = bench.load_adapter(CHIP_DIR, conf)
+    cfg = bench.arch_config(conf, adapter)
     dtype = jnp.dtype(dep["dtype"])
     B, S = dep["max_batch"], dep["max_len"]
     topo = topologies.get_topology_desc(platform="tpu",
@@ -54,13 +53,9 @@ def main(name: str) -> None:
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
             tree)
 
-    table = make_initial_membership(dep["ep_ranks"], cfg.moe.num_experts,
-                                    dep["slots_per_rank"])
-    dpl = Deployment(moe=moe_mod.local_deployment(
-        table.num_slots, cfg.capacity_factor, dispatch=dep["dispatch"]))
-    params = placed(param_shapes(cfg, dtype, table.slot_to_expert,
-                                 table.num_slots))
-    caches = placed(jax.eval_shape(lambda: init_caches(cfg, B, S, dtype)))
+    parts = adapter.compile_parts(cfg, conf)
+    table, dpl = parts["table"], parts["deployment"]
+    params, caches = placed(parts["params"]), placed(parts["caches"])
     programs = {
         "serve_step": jax.jit(make_serve_step(cfg, dpl),
                               donate_argnums=(1,)).lower(

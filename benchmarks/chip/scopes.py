@@ -1,6 +1,8 @@
-"""Program stages in a JAX profiler trace (``.xplane.pb``): which
-``jax.named_scope`` each device operation ran under, and the program's own
-host spans (``repro.*``, opened through ``repro.obs.trace``).
+"""Program stages in a JAX profiler trace (``.xplane.pb``): the file's
+planes, lines and events (``read``), which ``jax.named_scope`` each device
+operation ran under, and the program's own host spans (``repro.*``, opened
+through ``repro.obs.trace``), over the ``xplane.Reduced`` that
+``xplane.reduce`` builds from ``read``.
 
     python3 benchmarks/chip/scopes.py <trace.xplane.pb | directory>
 
@@ -16,12 +18,11 @@ carries its own. The stage of an op is the innermost segment of its
 
 The file is read with the standard library alone: the protobuf wire format
 of ``XSpace`` (``xplane.proto``), only the fields named below. It is not
-read through ``jax.profiler.ProfileData``, as ``xplane.reduce`` reads it,
-because ProfileData (jax 0.9.0) exposes an event's own stats only
-(``device_offset_ps``, ``device_duration_ps``, ``Time Scale Multiplier``
-on a TPU op) and not its metadata's, where ``tf_op`` and ``program_id``
-are. The events it keeps are those ``xplane.reduce`` keeps, with the same
-times, so one reader can serve both.
+read through ``jax.profiler.ProfileData``, because ProfileData (jax 0.9.0)
+exposes an event's own stats only (``device_offset_ps``,
+``device_duration_ps``, ``Time Scale Multiplier`` on a TPU op) and not its
+metadata's, where ``tf_op`` and ``program_id`` are. The events and times
+are those ProfileData gives.
 """
 from __future__ import annotations
 
@@ -41,6 +42,8 @@ STAGES = ("attention", "moe.route", "moe.exchange", "moe.expert", "lm_head")
 HOST_PREFIX = "repro."
 FRONTEND_STEP, ENGINE_SYNC = "repro.frontend.step", "repro.engine.sync"
 SERVE_STEP = r"^jit_serve_step\("
+#: ops whose event spans their body's ops, which are events of their own
+CONTROL_FLOW = ("while", "conditional")
 
 # field numbers: XSpace.planes 1; XPlane name 2, lines 3, event_metadata 4
 # (map: key 1, value 2), stat_metadata 5; XLine name 2, timestamp_ns 3,
@@ -106,12 +109,12 @@ def _map_entries(entry):
 
 
 @dataclass
-class Trace:
-    """What ``read`` keeps of a trace, device times in ns."""
-    #: (HLO text, start, duration, op_name or "", program id) per op event
-    ops: list = field(default_factory=list)
-    modules: list = field(default_factory=list)   # (name, start, duration)
-    host: list = field(default_factory=list)      # (name, start, duration)
+class Plane:
+    """One plane of a trace: its name and its lines, in file order, each
+    ``(line name, [(event name, start ns, duration ns, stats)])`` where
+    ``stats`` are the event metadata's uint64 and string stats."""
+    name: str
+    lines: list = field(default_factory=list)
 
 
 def _plane(buf) -> tuple[str, list, list, dict]:
@@ -154,7 +157,8 @@ def _event_metadata(entries, snames) -> dict:
 
 
 def _line(buf) -> tuple[str, list]:
-    """(line name, [(metadata id, start ns, duration ns)])."""
+    """(line name, [(metadata id, start ns, duration ns)]), the times cut
+    to whole nanoseconds as ``jax.profiler.ProfileData`` gives them."""
     name, t0, events = "", 0, []
     for num, v in _fields(buf):
         if num == 2:
@@ -173,39 +177,29 @@ def _line(buf) -> tuple[str, list]:
                 off = v
             elif num == 3:
                 dur = v
-        out.append((mid, t0 + off / 1e3, dur / 1e3))
+        out.append((mid, t0 + off // 1000, dur // 1000))
     return name, out
 
 
-def read(path) -> Trace:
-    """The op and module events of every TPU plane, each op with its HLO
-    ``op_name`` (its ``tf_op`` stat, "" where it has none) and program id,
-    and the host events whose names start with ``repro.`` or ``bench.``."""
+def read(path) -> list[Plane]:
+    """Every plane of the trace with its lines and events: the names and
+    times ``jax.profiler.ProfileData`` gives (``test_scopes.py``), and each
+    event's metadata stats, among them an op's HLO ``op_name`` (``tf_op``)
+    and ``program_id``."""
     data = memoryview(pathlib.Path(path).read_bytes())
-    tr = Trace()
+    planes = []
     for num, buf in _fields(data):
         if num != 1:
             continue
         name, lines, emeta, snames = _plane(buf)
-        if xplane.DEVICE_PLANE.fullmatch(name):
-            meta = _event_metadata(emeta, snames)
-            for ln in lines:
-                lname, events = _line(ln)
-                if lname == xplane.MODULES:
-                    tr.modules += [(meta[m][0], s, d) for m, s, d in events]
-                elif lname == xplane.OPS:
-                    for m, s, d in events:
-                        text, stats = meta[m]
-                        tr.ops.append((text, s, d, stats.get("tf_op") or "",
-                                       stats.get("program_id")))
-        elif name == xplane.HOST_PLANE:
-            meta = _event_metadata(emeta, snames)
-            for ln in lines:
-                _, events = _line(ln)
-                tr.host += [(meta[m][0], s, d) for m, s, d in events
-                            if meta[m][0].startswith((HOST_PREFIX,
-                                                      "bench."))]
-    return tr
+        meta = _event_metadata(emeta, snames)
+        plane = Plane(name)
+        for ln in lines:
+            lname, events = _line(ln)
+            plane.lines.append((lname, [(meta[m][0], s, d, meta[m][1])
+                                        for m, s, d in events]))
+        planes.append(plane)
+    return planes
 
 
 def stage_of(op_name: str, stages=STAGES) -> Optional[str]:
@@ -222,11 +216,14 @@ def _program_id(module_name: str) -> Optional[int]:
     return int(m.group(1)) if m else None
 
 
-def stage_ms(tr: Trace, module: str = SERVE_STEP, stages=STAGES) -> dict:
-    """Device time per execution of ``module`` (ms): summed over the ops
-    of each complete execution in the trace, by stage, plus ``unscoped``
-    (ops in no stage) and ``total`` (every op of the execution);
-    ``executions`` counts them. Empty when the trace holds none."""
+def stage_ms(tr, module: str = SERVE_STEP, stages=STAGES) -> dict:
+    """Device time per execution of ``module`` (ms) in an
+    ``xplane.Reduced``: summed over the ops of each complete execution in
+    the trace, by the innermost of ``stages`` each op ran under, plus
+    ``unscoped`` (ops in no stage) and ``total`` (every op of the
+    execution); ``executions`` counts them. A loop's or a branch's own op
+    event spans the ops of its body, which are events of their own, and is
+    left out. Empty when the trace holds none."""
     rx = re.compile(module)
     runs = sorted((s, s + d, _program_id(n)) for n, s, d in tr.modules
                   if rx.search(n))
@@ -234,9 +231,10 @@ def stage_ms(tr: Trace, module: str = SERVE_STEP, stages=STAGES) -> dict:
         return {}
     starts = [r[0] for r in runs]
     sums = dict.fromkeys(stages + ("unscoped",), 0.0)
-    for _, s, d, op_name, pid in tr.ops:
+    for text, s, d, op_name, pid in tr.ops:
         k = bisect.bisect_right(starts, s) - 1
-        if k < 0 or s > runs[k][1] or pid != runs[k][2]:
+        if (k < 0 or s > runs[k][1] or pid != runs[k][2]
+                or xplane.opcode(text) in CONTROL_FLOW):
             continue
         stage = stage_of(op_name, stages) or "unscoped"
         sums[stage] += d
@@ -246,7 +244,7 @@ def stage_ms(tr: Trace, module: str = SERVE_STEP, stages=STAGES) -> dict:
     return out
 
 
-def span_ms_per_step(tr: Trace, outer: str = FRONTEND_STEP) -> dict:
+def span_ms_per_step(tr, outer: str = FRONTEND_STEP) -> dict:
     """Each ``repro.*`` host span's time per ``outer`` span (ms), the
     ``outer`` spans included, over the spans that lie inside one: a trace
     starts and stops at any point of a step, so it can hold the inner
@@ -263,7 +261,7 @@ def span_ms_per_step(tr: Trace, outer: str = FRONTEND_STEP) -> dict:
     return out
 
 
-def host_step_ms(tr: Trace, outer: str = FRONTEND_STEP,
+def host_step_ms(tr, outer: str = FRONTEND_STEP,
                  inner: str = ENGINE_SYNC) -> Optional[float]:
     """Mean over the ``outer`` spans of the trace of the span less its
     ``inner`` children (ms): the engine's host time on the critical path
@@ -272,25 +270,13 @@ def host_step_ms(tr: Trace, outer: str = FRONTEND_STEP,
     return per[outer] - per.get(inner, 0.0) if per else None
 
 
-def named_gaps(tr: Trace, n: int = 10) -> list[list]:
-    """The ``n`` longest idle gaps between device ops, each named by the
-    innermost ``repro.*`` or ``bench.*`` host span over its middle."""
-    r = xplane.Reduced(ops=[(t, s, d) for t, s, d, _, _ in tr.ops],
-                       host=tr.host)
-    return r.idle_gaps(n)
-
-
-def find(path) -> pathlib.Path:
-    p = pathlib.Path(path)
-    return xplane.find_xplane(p) if p.is_dir() else p
-
-
 def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else argv
-    tr = read(find(argv[0]))
+    path = pathlib.Path(argv[0])
+    tr = xplane.reduce(xplane.find_xplane(path) if path.is_dir() else path)
     print(json.dumps({"stage_ms": stage_ms(tr),
                       "engine_host_ms": host_step_ms(tr),
-                      "idle_gaps": named_gaps(tr)}))
+                      "idle_gaps": tr.idle_gaps()}))
 
 
 if __name__ == "__main__":

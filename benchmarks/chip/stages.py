@@ -6,8 +6,8 @@
 
 Runs the cell as ``bench.py --trace 0`` does, records the profiler over
 the window's last ``bench.TRACE_S`` seconds into a directory of its own
-(from a thread beside the harness's loop), reads it with ``scopes.read``
-and prints one JSON line:
+(from a thread beside the harness's loop), reduces it with
+``xplane.reduce`` and prints one JSON line:
 
 - ``result``: the cell's own result (metrics, ``correct``, checks);
 - ``stage_ms``: device time of one ``jit_serve_step`` execution by named
@@ -78,7 +78,7 @@ def measure(spec: dict, workload: str, seed: int, seconds: float,
         finally:
             done.set()
             tracer.join()
-        tr = scopes.read(xplane.find_xplane(directory))
+        tr = xplane.reduce(xplane.find_xplane(directory))
     t0, t1 = window
     return {
         "workload": workload, "seed": seed,
@@ -87,7 +87,7 @@ def measure(spec: dict, workload: str, seed: int, seconds: float,
         "stage_ms": scopes.stage_ms(tr),
         "engine_host_ms": scopes.host_step_ms(tr),
         "span_ms_per_step": scopes.span_ms_per_step(tr),
-        "idle_gaps": scopes.named_gaps(tr),
+        "idle_gaps": tr.idle_gaps(),
         "steps_per_s": {"traced": steps_per_s(cell.run, t0, t1),
                         "untraced": steps_per_s(cell.run, 2 * t0 - t1, t0)}}
 

@@ -29,24 +29,34 @@ TINY_CLOSED.update(closed={"block": 16},
                    sample={"min_tokens": 30, "max_requests": 5})
 
 
+def tiny_conf() -> dict:
+    """The tiny Mixtral-shaped configuration: ``mixtral-8x22b``'s file at
+    ``TINY_MODEL``'s widths, in float32, 8 rows of 160 positions."""
+    conf = json.loads((CHIP / "configs" / "mixtral-8x22b.json").read_text())
+    conf["name"] = "tiny"
+    conf["model"].update(TINY_MODEL)
+    conf["deployment"].update(dtype="float32", max_batch=8, max_len=160)
+    return conf
+
+
 def tiny_tree(tmp: pathlib.Path, mix: dict, name: str = "tiny",
-              end_to_end=tuple(END_TO_END)):
-    """A copy of the benchmark's files plus a tiny configuration and a new
-    mix file; returns (spec, chip_dir, root) for ``bench.CellRun``. No file
-    of the copy is edited: the cell is found by the names alone."""
+              end_to_end=tuple(END_TO_END), conf=None, adapter=None):
+    """A copy of the benchmark's files plus a configuration (``tiny_conf``
+    unless given), the source of its adapter where given, and a new mix
+    file; returns (spec, chip_dir, root) for ``bench.CellRun``. No file of
+    the copy is edited: the cell is found by the names alone."""
+    conf = conf or tiny_conf()
     root = tmp / "tree"
     chip = root / "benchmarks" / "chip"
     shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns(
         "__pycache__", "tests"))
-    conf = json.loads((chip / "configs" / "mixtral-8x22b.json").read_text())
-    conf["name"] = "tiny"
-    conf["model"].update(TINY_MODEL)
-    conf["deployment"].update(dtype="float32", max_batch=8, max_len=160)
-    (chip / "configs" / "tiny.json").write_text(json.dumps(conf))
+    conf_file = f"benchmarks/chip/configs/{conf['name']}.json"
+    (root / conf_file).write_text(json.dumps(conf))
+    if adapter is not None:
+        (chip / "adapters" / f"{conf['adapter']}.py").write_text(adapter)
     (chip / "traffic" / f"{name}.json").write_text(json.dumps(mix))
-    spec = {"configs": [{"name": "tiny",
-                         "file": "benchmarks/chip/configs/tiny.json"}],
-            "workloads": [{"name": "tiny", "config": "tiny",
+    spec = {"configs": [{"name": conf["name"], "file": conf_file}],
+            "workloads": [{"name": "tiny", "config": conf["name"],
                            "traffic": name, "chips": 1}],
             "end_to_end": [{"name": n, "unit": END_TO_END[n]}
                            for n in end_to_end],
@@ -54,4 +64,3 @@ def tiny_tree(tmp: pathlib.Path, mix: dict, name: str = "tiny",
                           for p in sorted((chip / "metrics").glob("*.py"))
                           if p.stem not in END_TO_END]}
     return spec, chip, root
-

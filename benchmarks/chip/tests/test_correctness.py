@@ -36,10 +36,10 @@ def test_reference_agrees_with_the_program_model_path():
         prefill
     from repro.models.moe import local_deployment
 
-    conf = {"name": "tiny", "model": _model(),
-            "deployment": {"slots_per_rank": 1, "ranks_per_host": 2,
-                           "hosts_per_switch": 2, "dispatch": "ragged",
-                           "kv_block_size": 16}}
+    conf = {"name": "tiny", "adapter": "mixtral", "model": _model(),
+            "deployment": {"ep_ranks": 16, "slots_per_rank": 1,
+                           "ranks_per_host": 2, "hosts_per_switch": 2,
+                           "dispatch": "ragged", "kv_block_size": 16}}
     cfg = bench.arch_config(conf)
     table = make_initial_membership(16, 8, 1)
     params = init_params(cfg, jax.random.key(SEED), jnp.float32,

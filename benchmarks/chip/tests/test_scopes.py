@@ -1,9 +1,11 @@
-"""Program stages in a trace (``scopes.py``): the scope of each device op,
-the stage split of a step, the host time of the program's own spans and
-the idle gaps they name. On two traces recorded on a TPU v5e: the
-scoped one by ``record_scoped_trace.py`` (three executions of
-``jit_scoped_step`` inside ``repro.*`` spans) and the small one by
-``record_trace.py``, whose readings by the current readers are pinned."""
+"""Program stages in a trace (``scopes.py``): the events ``scopes.read``
+takes from the file, the scope of each device op, the stage split of a
+step, the host time of the program's own spans and the idle gaps they
+name, all over the ``xplane.Reduced`` that ``xplane.reduce`` builds. On
+traces recorded on a TPU v5e: the scoped one by ``record_scoped_trace.py``
+(three executions of ``jit_scoped_step`` inside ``repro.*`` spans), the
+small one by ``record_trace.py``, whose readings by the readers are
+pinned, and the serve step's by ``record_serve_trace.py``."""
 import pathlib
 
 import pytest
@@ -13,6 +15,7 @@ import xplane
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 SCOPED = r"^jit_scoped_step\("
+TRACES = ["tpu_small", "tpu_scoped", "tpu_serve"]
 
 
 def _load(name):
@@ -25,7 +28,7 @@ def _load(name):
 
 @pytest.fixture(scope="module")
 def scoped():
-    return scopes.read(_load("tpu_scoped.xplane.pb"))
+    return xplane.reduce(_load("tpu_scoped.xplane.pb"))
 
 
 def test_stage_is_the_innermost_named_scope():
@@ -84,7 +87,7 @@ def test_idle_gaps_named_by_a_program_span(scoped):
     ``repro.engine.sync`` returns, and starts 0.8 ms before its dispatch),
     so the 3.3 ms gap that 2 ms of bookkeeping and the next dispatch make
     is named by the ``repro.engine.sync`` span before them."""
-    gaps = scopes.named_gaps(scoped, 2)
+    gaps = scoped.idle_gaps(2)
     assert [g[0] for g in gaps] == [scopes.ENGINE_SYNC] * 2
     assert all(g[1] >= 0.002 for g in gaps)
     ends = [s + d for n, s, d in scoped.host if n == scopes.ENGINE_SYNC]
@@ -95,8 +98,8 @@ def test_idle_gaps_named_by_a_program_span(scoped):
 
 
 def test_no_program_spans_reads_nothing():
-    assert scopes.host_step_ms(scopes.Trace()) is None
-    assert scopes.stage_ms(scopes.Trace(), SCOPED) == {}
+    assert scopes.host_step_ms(xplane.Reduced()) is None
+    assert scopes.stage_ms(xplane.Reduced(), SCOPED) == {}
 
 
 # -- the current readers' view of the small trace, pinned -------------------
@@ -120,20 +123,22 @@ def test_small_trace_readings_unchanged(small):
     assert small.idle_gaps(1)[0][1] == pytest.approx(918.897e-6, rel=1e-6)
 
 
-@pytest.mark.parametrize("name", ["tpu_small", "tpu_scoped"])
+@pytest.mark.parametrize("name", TRACES)
 def test_same_events_as_the_harness_reduction(name):
-    """``scopes.read`` keeps the events ``xplane.reduce`` keeps, with the
-    same names and times (to the nanosecond ProfileData rounds to), so one
-    reader can serve both."""
+    """``scopes.read`` gives every plane, line and event that
+    ``jax.profiler.ProfileData`` gives, with the same names and times."""
+    from jax.profiler import ProfileData
+
     path = _load(f"{name}.xplane.pb")
-    raw = scopes.read(path)
-    red = xplane.reduce(path, host_prefix=(scopes.HOST_PREFIX, "bench."))
-    for mine, theirs in ((raw.ops, red.ops), (raw.modules, red.modules)):
-        mine = sorted((n, s, d) for n, s, d, *_ in mine)
-        assert [n for n, _, _ in mine] == [n for n, _, _ in sorted(theirs)]
-        for (_, s, d), (_, s2, d2) in zip(mine, sorted(theirs)):
-            assert abs(s - s2) <= 1.0 and abs(d - d2) <= 1.0
-    assert sorted(raw.host) == sorted(red.host)
+    mine = scopes.read(path)
+    theirs = ProfileData.from_file(str(path)).planes
+    assert [p.name for p in mine] == [p.name for p in theirs]
+    for p, q in zip(mine, theirs):
+        assert [n for n, _ in p.lines] == [ln.name for ln in q.lines]
+        for (_, evs), ln in zip(p.lines, q.lines):
+            assert [(n, float(s), float(d)) for n, s, d, _ in evs] == [
+                (ev.name, float(ev.start_ns), float(ev.duration_ns))
+                for ev in ln.events]
 
 
 # -- the engine's spans through the harness, on the CPU -----------------------
@@ -168,9 +173,10 @@ def test_current_readers_on_the_small_trace(small):
     import bench
     run = bench.Run(cell={}, model={}, deployment={}, peak={}, seconds=1.0,
                     t_process=0.0, trace=small, trace_window=(0.0, 1e-3))
+    serve_step = ("step_device_ms", "step_mfu", "gmm_roofline",
+                  "attention_ms", "moe_route_ms", "moe_exchange_ms",
+                  "moe_expert_ms", "lm_head_ms")
     read = {n: bench.load_reader(bench.CHIP_DIR, n)(run)
-            for n in ("device_idle_share", "step_device_ms", "step_mfu",
-                      "gmm_roofline")}
+            for n in ("device_idle_share",) + serve_step}
     assert read["device_idle_share"] == pytest.approx(94.8124, rel=1e-6)
-    assert read["step_device_ms"] is read["step_mfu"] is \
-        read["gmm_roofline"] is None
+    assert all(read[n] is None for n in serve_step)

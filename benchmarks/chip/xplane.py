@@ -1,13 +1,15 @@
 """Reduction of a JAX profiler trace (``.xplane.pb``) to what the metric
 readers need: the device's busy time, the executions of a jitted module,
-the calls of a kernel, the busiest device operations, and the idle gaps
-labelled by the harness's host span that was open across them.
+the calls of a kernel, each op's HLO ``op_name`` (its named scopes, which
+``scopes.stage_ms`` reads), the busiest device operations, and the idle
+gaps labelled by the host span that was open across them.
 
 Device planes are named ``/device:TPU:<n>``; their ``XLA Modules`` line holds
 one event per execution of a compiled program (``jit_<name>(<id>)``) and
 their ``XLA Ops`` line one event per operation, kernels included. The host
 plane ``/host:CPU`` holds the ``jax.profiler.TraceAnnotation`` spans that
-the harness opens around its own calls.
+the harness opens around its own calls (``bench.*``) and the program's own
+(``repro.*``). The file is read by ``scopes.read``.
 """
 from __future__ import annotations
 
@@ -26,7 +28,8 @@ class Reduced:
     busy_s: float = 0.0             # union of op intervals, mean over chips
     span_s: float = 0.0             # first op start to last op end
     modules: list = field(default_factory=list)   # (name, start_ns, dur_ns)
-    ops: list = field(default_factory=list)       # (name, start_ns, dur_ns)
+    #: (HLO text, start_ns, dur_ns, HLO op_name or "", program id) per op
+    ops: list = field(default_factory=list)
     host: list = field(default_factory=list)      # (name, start_ns, dur_ns)
     line_names: dict = field(default_factory=dict)
 
@@ -37,12 +40,12 @@ class Reduced:
 
     def op_durations(self, pattern: str) -> list[float]:
         rx = re.compile(pattern)
-        return [d * 1e-9 for n, _, d in self.ops if rx.search(n)]
+        return [d * 1e-9 for n, _, d, *_ in self.ops if rx.search(n)]
 
     def top_ops(self, n: int = 10) -> list[list]:
         """The ``n`` operations with the most device time, by name."""
         tot: dict[str, float] = {}
-        for name, _, d in self.ops:
+        for name, _, d, *_ in self.ops:
             k = short_name(name)
             tot[k] = tot.get(k, 0.0) + d * 1e-9
         return [[k, v] for k, v in
@@ -52,7 +55,7 @@ class Reduced:
         """The ``n`` longest gaps between device ops, each named by the
         innermost host span covering its middle (``idle`` if none)."""
         gaps = [(s, d) for s, d in _gaps(_merge(
-            (s, s + d) for _, s, d in self.ops))]
+            (s, s + d) for _, s, d, *_ in self.ops))]
         gaps.sort(key=lambda g: -g[1])
         out = []
         for s, d in gaps[:n]:
@@ -65,6 +68,12 @@ class Reduced:
 
 _OP = re.compile(r"^(%[\w.\-]+) = .*? ([a-z][\w\-]*)\(")
 _WEIGHTS = re.compile(r"bf16\[\d+,\d+,\d+\]")
+
+
+def opcode(hlo: str) -> str:
+    """The opcode of an op event's HLO text ("" where it has none)."""
+    m = _OP.match(hlo)
+    return m.group(2) if m else ""
 
 
 def short_name(hlo: str) -> str:
@@ -107,28 +116,32 @@ def busy_ns(intervals) -> float:
     return sum(e - s for s, e in _merge(intervals))
 
 
-def reduce(path, host_prefix: str = "bench.") -> Reduced:
-    from jax.profiler import ProfileData
+def reduce(path, host_prefix=("bench.", "repro.")) -> Reduced:
+    """The trace at ``path`` as the readers get it: every TPU plane's op
+    and module events and the host spans named ``host_prefix``."""
+    import scopes    # here: scopes imports this module
 
-    pd = ProfileData.from_file(str(path))
     r = Reduced()
     busy, spans = [], []
-    for plane in pd.planes:
-        lines = {ln.name: [(ev.name, float(ev.start_ns), float(ev.duration_ns))
-                           for ev in ln.events] for ln in plane.lines}
+    for plane in scopes.read(path):
         if DEVICE_PLANE.fullmatch(plane.name):
             r.planes += 1
-            r.line_names[plane.name] = sorted(lines)
-            ops = lines.get(OPS, [])
+            r.line_names[plane.name] = sorted({n for n, _ in plane.lines})
+            ops = [(n, float(s), float(d), st.get("tf_op") or "",
+                    st.get("program_id"))
+                   for ln, evs in plane.lines if ln == OPS
+                   for n, s, d, st in evs]
             r.ops += ops
-            r.modules += lines.get(MODULES, [])
+            r.modules += [(n, float(s), float(d))
+                          for ln, evs in plane.lines if ln == MODULES
+                          for n, s, d, _ in evs]
             if ops:
-                busy.append(busy_ns((s, s + d) for _, s, d in ops))
-                spans.append(max(s + d for _, s, d in ops)
-                             - min(s for _, s, _ in ops))
+                busy.append(busy_ns((s, s + d) for _, s, d, *_ in ops))
+                spans.append(max(s + d for _, s, d, *_ in ops)
+                             - min(s for _, s, *_ in ops))
         elif plane.name == HOST_PLANE:
-            for evs in lines.values():
-                r.host += [e for e in evs if e[0].startswith(host_prefix)]
+            r.host += [(n, float(s), float(d)) for _, evs in plane.lines
+                       for n, s, d, _ in evs if n.startswith(host_prefix)]
     if r.planes:
         r.busy_s = sum(busy) / r.planes * 1e-9
         r.span_s = (max(spans) if spans else 0.0) * 1e-9
